@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from benchmarks import timing
 from repro.db import JOIN_VARIANTS, Database
 from repro.fabric import MeshTransport, netsim, sim
+from repro.launch.mesh import make_mesh
 
 DEFAULT_PROFILES = ("rdma_fdr4x",)       # the paper's measured cluster
 ROUTE_CHUNKS = 4                         # double-buffer depth for the A/B
@@ -92,7 +93,7 @@ def run(profiles=None, timed=False):
     rows = []
     measured = {}
     n = 1 << 20
-    mesh = jax.make_mesh((jax.device_count(),)[:1], ("data",))
+    mesh = make_mesh((jax.device_count(),)[:1], ("data",))
     db = Database(transport=MeshTransport(mesh, "data",
                                           profile=profiles[0]))
     db.create_table("R", n, payload_words=1, partitioning="hash")

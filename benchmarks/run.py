@@ -45,6 +45,9 @@ from benchmarks import (fig2_microbench, fig6_rsi, fig7_costmodel,
                         fig8a_joins, fig8b_agg, fig9_ml, fig10_contention,
                         fig_scale, fig_serve)
 from repro.fabric import netsim
+from repro.launch.cache import enable_compile_cache
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 MODULES = {
     "fig2": fig2_microbench,
@@ -119,6 +122,7 @@ def main() -> None:
     else:
         profiles = (netsim.get_profile(args.profile).name,)
     names = [args.only] if args.only else sorted(MODULES, key=_figure_key)
+    enable_compile_cache(ROOT)
     if args.json:
         os.makedirs(args.json, exist_ok=True)
     print("name,us_per_call,derived")

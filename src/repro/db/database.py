@@ -233,24 +233,29 @@ class Database:
             raise ValueError(f"one table per commit wave, got {names}")
         t = self.table(names.pop())
         txns, cids = self._pack_txns(t, sessions)
-        T = len(sessions)
         ok, t.store = self._jit_commit(isolation, chunks,
                                        f"{t.schema.name}/")(
             t.store, txns,
             None if priority is None else jnp.asarray(priority, jnp.int32))
-        if self.transport.n > 1:
-            # msg 3 completion: the routed commit body only flips bitvector
-            # bits inside each client shard's local range, but the facade's
-            # oracle hands out *globally* contiguous cids (scalar SI
-            # timestamps), so the out-of-range flips are finished here —
-            # unsignaled one-sided WRITEs of the clients' own slots
-            # (committed and aborted txns both burn theirs)
-            t.store["bitvec"] = self.transport.write(
-                t.store["bitvec"], jnp.asarray(cids, jnp.int32),
-                jnp.ones((T,), bool), region=f"{t.schema.name}/bitvec")
+        self._complete_bitvec(t, cids)
         ok = np.asarray(ok)
         self._assign_outcomes(sessions, ok, cids)
         return np.asarray([s.committed for s in wave], bool)
+
+    def _complete_bitvec(self, t: Table, cids):
+        """msg 3 completion: the routed commit body only flips bitvector
+        bits inside each client shard's local range, but the facade's
+        oracle hands out *globally* contiguous cids (scalar SI
+        timestamps), so on a sharded NAM the out-of-range flips are
+        finished here — unsignaled one-sided WRITEs of the clients' own
+        slots (committed and aborted txns both burn theirs).  The store
+        then goes back to its home-shard layout."""
+        if self.transport.n > 1:
+            t.store["bitvec"] = self.transport.write(
+                t.store["bitvec"], jnp.asarray(cids, jnp.int32),
+                jnp.ones((len(cids),), bool),
+                region=f"{t.schema.name}/bitvec")
+            t.store = self.transport.place(t.store)
 
     def _assign_outcomes(self, sessions, ok, cids):
         for s, committed, cid in zip(sessions, np.asarray(ok), cids):
@@ -365,13 +370,7 @@ class Database:
                 None if priority is None else
                 [jnp.asarray(p, jnp.int32) for p in priority])
             ok = np.concatenate([np.asarray(o) for o in oks])
-            if self.transport.n > 1:
-                # msg 3 completion for globally contiguous cids, as in
-                # :meth:`commit`
-                t.store["bitvec"] = self.transport.write(
-                    t.store["bitvec"], jnp.asarray(cids, jnp.int32),
-                    jnp.ones((len(cids),), bool),
-                    region=f"{t.schema.name}/bitvec")
+            self._complete_bitvec(t, cids)
             self._assign_outcomes(
                 [s for g in writer_groups for s in g], ok, cids)
         self._retry_losers(flat, chunks=1, max_retries=max_retries)
@@ -453,13 +452,7 @@ class Database:
                 chunks, f"{table.schema.name}/", len(txns_list))(
                 table.store, txns_list)
             for (sessions, cids), ok in zip(writer_meta, oks):
-                if self.transport.n > 1:
-                    # msg 3 completion for globally contiguous cids, as in
-                    # :meth:`commit`
-                    table.store["bitvec"] = self.transport.write(
-                        table.store["bitvec"], jnp.asarray(cids, jnp.int32),
-                        jnp.ones((len(cids),), bool),
-                        region=f"{table.schema.name}/bitvec")
+                self._complete_bitvec(table, cids)
                 self._assign_outcomes(sessions, ok, cids)
         self._retry_losers([s for w in waves for s in w], chunks=chunks,
                            max_retries=max_retries)

@@ -66,9 +66,9 @@ class Table:
         pool.alloc(f"{schema.name}/bitvec", (num_timestamps,), bool,
                    ("record",))
         pool.alloc(f"{schema.name}/keys", (R,), jnp.uint32, ("record",))
-        self.store = rsi.init_store(self.cfg)
+        self.store = transport.place(rsi.init_store(self.cfg))
         # default join key = record id (OLTP tables); bulk loads replace it
-        self.keys = jnp.arange(R, dtype=jnp.uint32)
+        self.keys = transport.place(jnp.arange(R, dtype=jnp.uint32))
         self.rows = 0              # live rows, feeds the planner's stats
 
     # -------------------------------------------------------------- load --
@@ -82,13 +82,15 @@ class Table:
         R = self.schema.num_records
         if n > R:
             raise ValueError(f"{n} rows > {R} records")
-        self.keys = jnp.zeros((R,), jnp.uint32).at[:n].set(keys)
+        self.keys = self._transport.place(
+            jnp.zeros((R,), jnp.uint32).at[:n].set(keys))
         pay = jnp.zeros((R, self.schema.version_slots,
                          self.schema.payload_words), WORD)
         self.store["payload"] = pay.at[:n, 0, 0].set(vals)
         self.store["cids"] = jnp.zeros(
             (R, self.schema.version_slots), WORD).at[:n, 0].set(cid)
         self.store["words"] = jnp.zeros((R,), WORD).at[:n].set(cid)
+        self.store = self._transport.place(self.store)
         self.rows = n
         return self
 
@@ -102,6 +104,7 @@ class Table:
         if vals is not None:
             self.store["payload"] = self.store["payload"].at[recs, 0].set(
                 jnp.asarray(vals, WORD))
+        self.store = self._transport.place(self.store)
         self.rows = max(self.rows, int(np.max(np.asarray(recs))) + 1)
         return self
 

@@ -642,7 +642,8 @@ ROUTE_CAP = 32
 
 def _mesh_transport():
     from repro.fabric import MeshTransport
-    mesh = jax.make_mesh((1,), ("fabric",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("fabric",))
     return MeshTransport(mesh, "fabric")
 
 

@@ -35,10 +35,12 @@ parallel work) instead of the former ``argsort`` + ``searchsorted`` — no
 ``sort`` primitive anywhere in a routed trace (guarded by tests).  Per-shard
 A shrinks as n grows under a sharded mesh, so A·n stays ~the global batch.
 
-On TPU the scatter-into-buffers step can instead run the Pallas
-``repro.kernels.radix_partition`` kernel (software-managed buffers in VMEM;
-``backend="pallas"``, the default when the backend is TPU); the jnp scatter
-is the fallback everywhere else and the reference semantics.
+On TPU the scatter-into-buffers step instead runs the Pallas
+``repro.kernels.radix_partition`` kernel (``backend="pallas"``, the default
+when the route is compiled for a TPU): the plan's slot map is inverted and
+the kernel fills each wire slot from its source row, valid lane included.
+The jnp scatter runs everywhere else and is the reference semantics; the
+two are bit-identical.
 
 A :class:`RoutePlan` (:func:`plan_route`) precomputes the slot assignment
 for a given ``dest`` so protocols with identical routing across rounds —
@@ -60,8 +62,8 @@ WORD = jnp.uint32
 WORD_BYTES = 4
 
 #: scatter backends: "jnp" = pure-jnp reference scatter, "pallas" = the
-#: kernels/radix_partition software-managed-buffer kernel (TPU); None = auto
-#: (pallas iff the default backend is TPU).
+#: kernels/radix_partition software-managed-buffer kernel; None = by the
+#: platform the route is compiled for (pallas on TPU, jnp elsewhere).
 ROUTE_BACKENDS = ("jnp", "pallas")
 
 
@@ -142,10 +144,9 @@ def pack_fields(fields, valid: bool = True):
     keep the zero lane, so occupancy travels inside the rows for free).
     Returns (packed, treedef, leaf_specs).
 
-    ``valid=False`` omits the trailing valid lane — the fused Pallas
-    scatter path (``kernels.radix_partition(fuse_valid=True)``) appends
-    it inside the kernel as each row lands, so binning and wire-packing
-    are one kernel pass."""
+    ``valid=False`` omits the trailing valid lane — the Pallas scatter
+    path (``kernels.radix_partition``) appends it inside the kernel as
+    each row lands."""
     leaves, treedef = jax.tree_util.tree_flatten(fields)
     specs = [(l.shape, l.dtype) for l in leaves]
     A = leaves[0].shape[0] if leaves else 0
@@ -249,9 +250,12 @@ def plan_route(dest, *, n: int, cap: int, window: int = 0) -> RoutePlan:
 # ------------------------------------------------------------- scatter ---
 
 def _scatter_rows(rows, plan: RoutePlan, mask):
-    """Reference scatter of packed rows into the (n*cap, w) wire buffer."""
+    """Reference scatter of valid-less packed rows into the (n*cap, w+1)
+    wire buffer, valid lane appended."""
     slot = plan.slot if mask is None else jnp.where(
         mask & plan.keep, plan.slot, plan.n * plan.cap)
+    rows = jnp.concatenate([rows, jnp.ones((rows.shape[0], 1), WORD)],
+                           axis=1)
     buf = jnp.zeros((plan.n * plan.cap, rows.shape[1]), WORD)
     return buf.at[slot].set(rows, mode="drop")
 
@@ -275,32 +279,27 @@ def _invert_plan(plan: RoutePlan, mask) -> jnp.ndarray:
         jnp.arange(A, dtype=jnp.int32), mode="drop", unique_indices=True)
 
 
-def _pallas_scatter_rows(rows, dest, n: int, cap: int):
-    """Scatter via the Pallas software-managed-buffer radix partitioner
-    (TPU): same first-come / capped / filtered semantics as the reference
-    scatter, binning done bucket-parallel in VMEM.  ``rows`` are the
+def _pallas_scatter_rows(rows, plan: RoutePlan, mask):
+    """Scatter via the Pallas radix partitioner (TPU): the same slots as
+    :func:`_scatter_rows`, filled from the inverted plan.  ``rows`` are the
     valid-less packed lanes (``pack_fields(valid=False)``); the kernel
-    appends the valid lane itself (``fuse_valid=True``), returning the
-    full wire rows in one pass."""
+    appends the valid lane itself, returning the full wire rows."""
     from repro.kernels import ops
-    A, w = rows.shape
-    bn = 256
-    pad = (-A) % bn
-    if pad:
-        rows = jnp.pad(rows, ((0, pad), (0, 0)))
-        dest = jnp.pad(dest.astype(jnp.int32), (0, pad),
-                       constant_values=-1)
-    out, _ = ops.radix_partition(rows, dest.astype(jnp.int32), n, cap,
-                                 fuse_valid=True)
-    return out.reshape(n * cap, w + 1)
+    return ops.radix_partition(rows, _invert_plan(plan, mask))
 
 
-def _resolve_backend(backend: Optional[str]) -> str:
+def _scatter(rows, plan: RoutePlan, mask, backend: Optional[str]):
+    """The wire buffer from valid-less rows through the chosen backend;
+    ``None`` picks per platform when the route is lowered, so a program
+    compiled for a TPU takes the kernel wherever it was traced."""
     if backend is None:
-        return "pallas" if jax.default_backend() == "tpu" else "jnp"
+        return jax.lax.platform_dependent(rows, plan, mask,
+                                          tpu=_pallas_scatter_rows,
+                                          default=_scatter_rows)
     if backend not in ROUTE_BACKENDS:
         raise ValueError(f"backend {backend!r} not in {ROUTE_BACKENDS}")
-    return backend
+    return (_pallas_scatter_rows if backend == "pallas"
+            else _scatter_rows)(rows, plan, mask)
 
 
 # --------------------------------------------------------------- route ---
@@ -342,12 +341,12 @@ def route(fields, dest=None, *, n: Optional[int] = None,
         raise ValueError("mask= only applies to a reused plan=")
     if cap % chunks != 0:
         raise ValueError(f"cap={cap} not divisible by chunks={chunks}")
+    if plan is None:
+        plan = plan_route(dest, n=n, cap=cap)
+        mask = None
+    dropped = (plan.dropped if mask is None else
+               jnp.sum((plan.overflow & mask).astype(jnp.int32)))
     if overlap:
-        if plan is None:
-            plan = plan_route(dest, n=n, cap=cap)
-            mask = None
-        dropped = (plan.dropped if mask is None else
-                   jnp.sum((plan.overflow & mask).astype(jnp.int32)))
         rows, treedef, specs = pack_fields(fields)
         inv = _invert_plan(plan, mask)
         padded = jnp.concatenate(
@@ -373,25 +372,8 @@ def route(fields, dest=None, *, n: Optional[int] = None,
         sent, sent_valid = unpack_fields(restripe(sent_s), treedef, specs)
         recv_fields, valid = unpack_fields(restripe(recv_s), treedef, specs)
         return RouteResult(recv_fields, valid, dropped, sent, sent_valid)
-    if plan is None and _resolve_backend(backend) == "pallas":
-        # Fused pack+bin: rows travel valid-less and the kernel appends
-        # the valid lane as each row lands, so binning and wire-packing
-        # are one kernel pass over the batch.
-        rows, treedef, specs = pack_fields(fields, valid=False)
-        dest = dest.astype(jnp.int32)
-        deliverable = (dest >= 0) & (dest < n)
-        counts = jnp.zeros((n,), jnp.int32).at[
-            jnp.where(deliverable, dest, n)].add(1, mode="drop")
-        dropped = jnp.sum(jnp.maximum(counts - cap, 0))
-        buf = _pallas_scatter_rows(rows, dest, n, cap)
-    else:
-        rows, treedef, specs = pack_fields(fields)
-        if plan is None:
-            plan = plan_route(dest, n=n, cap=cap)
-            mask = None
-        dropped = (plan.dropped if mask is None else
-                   jnp.sum((plan.overflow & mask).astype(jnp.int32)))
-        buf = _scatter_rows(rows, plan, mask)
+    rows, treedef, specs = pack_fields(fields, valid=False)
+    buf = _scatter(rows, plan, mask, backend)
     sent, sent_valid = unpack_fields(buf, treedef, specs)
     if exchange is None:
         return RouteResult(sent, sent_valid, dropped, sent, sent_valid)

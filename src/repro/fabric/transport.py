@@ -389,6 +389,12 @@ class Transport:
         bool (single output) or tuple of bool — True = replicated output."""
         raise NotImplementedError
 
+    def place(self, regions):
+        """Lay a pytree of region arrays out on their home shards (axis 0
+        split across the transport's shards), so protocol bodies find
+        each shard's rows where they run."""
+        raise NotImplementedError
+
     def shard_index(self):
         raise NotImplementedError
 
@@ -418,6 +424,9 @@ class LocalTransport(Transport):
 
     def run(self, body, args, out_reps):
         return body(*args)
+
+    def place(self, regions):
+        return regions
 
     def shard_index(self):
         return jnp.int32(0)
@@ -460,15 +469,19 @@ class MeshTransport(Transport):
         return lambda v: _router.chunked_all_to_all(v, axis, n, cap, chunks)
 
     def run(self, body, args, out_reps):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         in_specs = tuple(P(self.axis) for _ in args)
         if isinstance(out_reps, bool):
             out_specs = P() if out_reps else P(self.axis)
         else:
             out_specs = tuple(P() if r else P(self.axis) for r in out_reps)
-        return shard_map(body, mesh=self.mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)(*args)
+        return jax.shard_map(body, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)(*args)
+
+    def place(self, regions):
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        return jax.device_put(regions,
+                              NamedSharding(self.mesh, P(self.axis)))
 
     def shard_index(self):
         return jax.lax.axis_index(self.axis)

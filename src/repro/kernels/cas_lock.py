@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
-
 LOCK_BIT_32 = jnp.uint32(1 << 31)
 
 
@@ -37,13 +35,13 @@ def _kernel(idx_ref, exp_ref, words_ref, out_words_ref, ok_ref, *, bn, nwords):
         r = idx[i]
         valid = (r >= 0) & (r < nwords)
         r_safe = jnp.where(valid, r, 0)
-        cur = pl.load(out_words_ref, (pl.ds(r_safe, 1),))[0]
+        cur = out_words_ref[pl.ds(r_safe, 1)][0]
         ok = valid & (cur == exp[i])
 
         @pl.when(ok)
         def _():
             locked = exp[i] | jnp.uint32(1 << 31)
-            pl.store(out_words_ref, (pl.ds(r_safe, 1),), locked[None])
+            out_words_ref[pl.ds(r_safe, 1)] = locked[None]
         ok_ref[pl.ds(i, 1)] = ok[None]
         return 0
 
@@ -73,7 +71,7 @@ def cas_lock(words, idx, expected, *, block_n: int = 256,
             jax.ShapeDtypeStruct((r,), jnp.uint32),
             jax.ShapeDtypeStruct((a,), jnp.bool_),
         ],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(idx, expected, words)

@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
-
 
 def _kernel(slot_ref, val_ref, table_ref, acc_sc, *, slots, bn):
     j = pl.program_id(0)
@@ -38,12 +36,17 @@ def _kernel(slot_ref, val_ref, table_ref, acc_sc, *, slots, bn):
         table_ref[...] = acc_sc[...]
 
 
-def grouped_agg(slot, vals, num_slots: int, *, block_n: int = 512,
+def grouped_agg(slot, vals, num_slots: int, *, block_n: int = 1024,
                 interpret: bool = True):
     """slot: (N,) int32 in [0, num_slots); vals: (N,).
-    Returns dense table (num_slots,) f32 of per-slot sums."""
+    Returns dense table (num_slots,) f32 of per-slot sums.
+
+    ``block_n`` is a multiple of 1024: XLA tiles a 1-D operand in 1024s
+    on TPU v5e, and Mosaic refuses a block that splits that tile."""
+    pad = (-slot.shape[0]) % block_n
+    slot = jnp.pad(slot, (0, pad), constant_values=-1)   # matches no slot
+    vals = jnp.pad(vals, (0, pad))
     n = slot.shape[0]
-    assert n % block_n == 0
     return pl.pallas_call(
         functools.partial(_kernel, slots=num_slots, bn=block_n),
         grid=(n // block_n,),
@@ -54,7 +57,7 @@ def grouped_agg(slot, vals, num_slots: int, *, block_n: int = 512,
         out_specs=pl.BlockSpec((num_slots,), lambda j: (0,)),
         out_shape=jax.ShapeDtypeStruct((num_slots,), jnp.float32),
         scratch_shapes=[pltpu.VMEM((num_slots,), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(slot, vals)

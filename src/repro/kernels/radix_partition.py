@@ -1,12 +1,21 @@
-"""Radix partitioner with software-managed buffers (paper §5.2, RRJ).
+"""Radix partitioner: rows into software-managed buffers (paper §5.2, RRJ).
 
-Scatters rows of `vals` into per-bucket fixed-capacity buffers. Grid is
-(bucket, token-block): the bucket axis is parallel; the token-block axis is
-sequential ("arbitrary") so a per-bucket running count in SMEM carries across
-blocks — the kernel-level twin of the remote buffer reservation + append
-pattern the paper uses for RDMA WRITEs.
+The router bins every request with its sort-free plan
+(``repro.fabric.router.plan_route``) and inverts it into ``src``: for each
+slot of the ``(S, w)`` wire buffer, the index of the request that lands
+there, or ``A`` (one past the batch) for an empty slot.  This kernel moves
+the rows: slot ``s`` receives row ``src[s]`` and a trailing valid lane (1
+where a row landed, 0 in an empty slot) — the router's packed wire format
+in one pass.
 
-VMEM: one (cap, D) bucket buffer + one (BN, D) input tile resident per step.
+TPU layout.  A join shuffles 2-word rows, which would pad 64x in 128-lane
+tiles, so rows travel transposed: words on sublanes, slots on lanes.  A
+block of ``sb`` slots is filled on the MXU by a one-hot matmul,
+``out[:, t] = sum_i rows[:, i] * (src[t] == i)``.  Each 32-bit word is
+split into byte planes first: bf16 products and f32 sums of 0..255 are
+exact, so the result is bit-exact.  The rows a slot block takes belong to
+one bucket and arrive in order, so they lie in one contiguous input range;
+the kernel DMAs only the input windows that range covers.
 """
 from __future__ import annotations
 
@@ -17,76 +26,80 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
+SLOT_BLOCK = 512        # slots (lanes) per output block
+WINDOW = 512            # input rows per DMA'd window
+WORD_CHUNK = 256        # row words (sublanes) per grid step
 
 
-def _kernel(bucket_ref, vals_ref, out_ref, count_ref, cnt_sm,
-            *, cap, bn, fuse_valid):
-    p = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _():
-        cnt_sm[0] = 0
-        out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
-
-    b = bucket_ref[...]                     # (BN,)
-    v = vals_ref[...]                       # (BN, D)
-    mask = (b == p)
-    start = cnt_sm[0]
-
-    def body(i, cnt):
-        @pl.when(mask[i] & (cnt < cap))
-        def _():
-            row = jax.lax.dynamic_slice_in_dim(v, i, 1, axis=0)
-            if fuse_valid:
-                # Fused wire-pack: append the valid lane as the row lands,
-                # so binning + packing is one pass (empty slots keep the
-                # zeroed lane from the j == 0 init above).
-                row = jnp.concatenate(
-                    [row, jnp.ones((1, 1), row.dtype)], axis=1)
-            out_ref[0, pl.ds(cnt, 1), :] = row
-        return cnt + jnp.where(mask[i], 1, 0)
-
-    cnt = jax.lax.fori_loop(0, bn, body, start)
-    cnt_sm[0] = cnt
-
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _():
-        count_ref[0] = jnp.minimum(cnt, cap)
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
 
 
-def radix_partition(vals, bucket, num_buckets: int, cap: int,
-                    *, block_n: int = 256, interpret: bool = True,
-                    fuse_valid: bool = False):
-    """vals: (N, D); bucket: (N,) int32 in [0, num_buckets).
-    Returns (out (num_buckets, cap, D), counts (num_buckets,)).
+def _kernel(src_ref, rows_hbm, out_ref, buf, bounds, sem, *, a, bw, hc,
+            valid_row):
+    c = pl.program_id(1)
+    src = src_ref[...]                                  # (1, sb) int32
+    sb = src.shape[1]
+    filled = src < a
+    bounds[0] = jnp.min(jnp.where(filled, src, a)) // bw
+    bounds[1] = jnp.max(jnp.where(filled, src, -1)) // bw + 1
+    out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
 
-    ``fuse_valid=True`` widens the output rows by one lane and writes a
-    ones valid lane alongside each landed row (the router's packed wire
-    format), returning (num_buckets, cap, D + 1)."""
-    n, d = vals.shape
-    assert n % block_n == 0, (n, block_n)
-    d_out = d + 1 if fuse_valid else d
-    grid = (num_buckets, n // block_n)
-    return pl.pallas_call(
-        functools.partial(_kernel, cap=cap, bn=block_n,
-                          fuse_valid=fuse_valid),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n,), lambda p, j: (j,)),
-            pl.BlockSpec((block_n, d), lambda p, j: (j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, cap, d_out), lambda p, j: (p, 0, 0)),
-            pl.BlockSpec((1,), lambda p, j: (p,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((num_buckets, cap, d_out), vals.dtype),
-            jax.ShapeDtypeStruct((num_buckets,), jnp.int32),
-        ],
-        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
-        compiler_params=_compat.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+    def window(wi, carry):
+        cp = pltpu.make_async_copy(
+            rows_hbm.at[pl.ds(pl.multiple_of(c * hc, 8), hc),
+                        pl.ds(pl.multiple_of(wi * bw, 128), bw)], buf, sem)
+        cp.start()
+        cp.wait()
+        rows = buf[...]                                 # (hc, bw) int32
+        ids = wi * bw + jax.lax.broadcasted_iota(jnp.int32, (bw, sb), 0)
+        onehot = (ids == src).astype(jnp.float32)       # (bw, sb)
+        planes = jnp.concatenate(
+            [jax.lax.shift_right_logical(rows, 8 * k) & 0xFF
+             for k in range(4)], axis=0).astype(jnp.float32)
+        part = jnp.dot(planes, onehot,
+                       preferred_element_type=jnp.float32).astype(jnp.int32)
+        word = part[:hc]
+        for k in range(1, 4):
+            word = word | (part[k * hc:(k + 1) * hc] << (8 * k))
+        out_ref[...] |= word
+        return carry
+
+    jax.lax.fori_loop(bounds[0], bounds[1], window, 0)
+    row = c * hc + jax.lax.broadcasted_iota(jnp.int32, (hc, sb), 0)
+    out_ref[...] = jnp.where(row == valid_row, filled.astype(jnp.int32),
+                             out_ref[...])
+
+
+def radix_partition(rows, src, *, interpret: bool = True):
+    """rows: (A, w) uint32 packed request rows; src: (S,) int32 — the row
+    each wire slot takes, ``A`` for an empty slot.  Returns the ``(S, w+1)``
+    uint32 wire buffer: ``out[s] = [rows[src[s]], 1]``, all zeros where
+    ``src[s] == A``."""
+    a, w = rows.shape
+    s = src.shape[0]
+    sb = min(SLOT_BLOCK, _round_up(s, 128))
+    bw = min(WINDOW, _round_up(max(a, 1), 128))
+    chunks = -(-(w + 1) // WORD_CHUNK)
+    hc = _round_up(-(-(w + 1) // chunks), 8)
+    h = chunks * hc
+    sp, ap = _round_up(s, sb), _round_up(max(a, 1), bw)
+    rows_t = jnp.pad(jax.lax.bitcast_convert_type(rows, jnp.int32).T,
+                     ((0, h - w), (0, ap - a)))
+    src = jnp.pad(src.astype(jnp.int32), (0, sp - s),
+                  constant_values=a)[None, :]
+    out = pl.pallas_call(
+        functools.partial(_kernel, a=a, bw=bw, hc=hc, valid_row=w),
+        grid=(sp // sb, h // hc),
+        in_specs=[pl.BlockSpec((1, sb), lambda b, c: (0, b)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((hc, sb), lambda b, c: (c, b)),
+        out_shape=jax.ShapeDtypeStruct((h, sp), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((hc, bw), jnp.int32),
+                        pltpu.SMEM((2,), jnp.int32),
+                        pltpu.SemaphoreType.DMA(())],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(bucket, vals)
+    )(src, rows_t)
+    return jax.lax.bitcast_convert_type(out[:w + 1, :s].T, jnp.uint32)

@@ -7,20 +7,12 @@ import jax.numpy as jnp
 from repro.kernels.cas_lock import LOCK_BIT_32
 
 
-def radix_partition(vals, bucket, num_buckets: int, cap: int):
-    """Stable order within bucket; overflow dropped."""
-    n, d = vals.shape
-    order = jnp.argsort(bucket, stable=True)
-    bs = bucket[order]
-    first = jnp.searchsorted(bs, bs, side="left")
-    pos = jnp.arange(n, dtype=jnp.int32) - first.astype(jnp.int32)
-    keep = pos < cap
-    flat = jnp.where(keep, bs * cap + pos, num_buckets * cap)
-    out = jnp.zeros((num_buckets * cap + 1, d), vals.dtype).at[flat].set(
-        vals[order], mode="drop")[:-1].reshape(num_buckets, cap, d)
-    counts = jnp.minimum(
-        jnp.zeros((num_buckets,), jnp.int32).at[bucket].add(1), cap)
-    return out, counts
+def radix_partition(rows, src):
+    """Wire slot s takes row src[s] plus a valid lane; src == A is empty."""
+    a, w = rows.shape
+    padded = jnp.concatenate([rows, jnp.zeros((1, w), rows.dtype)])
+    return jnp.concatenate(
+        [padded[src], (src < a).astype(rows.dtype)[:, None]], axis=1)
 
 
 def flash_attention(q, k, v, *, causal: bool = True):

@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
-
 
 def _kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, y_ref, st_sc, *, hb, l):
     ci = pl.program_id(2)
@@ -75,7 +73,7 @@ def ssd_scan(xh, bv, cv, dt, a, *, chunk: int = 128, head_block: int = 8,
                                lambda b, h, c: (b, c, h, 0)),
         out_shape=jax.ShapeDtypeStruct((B, S, H, hd), xh.dtype),
         scratch_shapes=[pltpu.VMEM((head_block, hd, N), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xh, bv, cv, dt, a.reshape(1, H))

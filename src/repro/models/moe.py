@@ -24,7 +24,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.sharding import current_policy
 
@@ -198,10 +197,11 @@ def _moe_rrj(cfg, mcfg, p, x):
 
     body = partial(_moe_rrj_body, cfg, mcfg, tp, cap, ecap)
     xspec = P(batch_axes or None, "model", None)
-    f = shard_map(body, mesh=mesh,
-                  in_specs=(xspec, P(None, None),
-                            P("model", "data", None), P("model", None, "data")),
-                  out_specs=xspec, check_rep=False)
+    f = jax.shard_map(body, mesh=mesh,
+                      in_specs=(xspec, P(None, None),
+                                P("model", "data", None),
+                                P("model", None, "data")),
+                      out_specs=xspec, check_vma=False)
     return f(x, p["router"], p["wi"], p["wo"])
 
 
@@ -272,10 +272,11 @@ def _moe_replicated(cfg, mcfg, p, x):
     batch_axes = pol.rules.get("batch") or ()
     xspec = P(batch_axes or None, None, None)
     body = partial(_moe_replicated_body, cfg, mcfg, tp, bool(batch_axes))
-    f = shard_map(body, mesh=mesh,
-                  in_specs=(xspec, P(None, None),
-                            P("model", "data", None), P("model", None, "data")),
-                  out_specs=xspec, check_rep=False)
+    f = jax.shard_map(body, mesh=mesh,
+                      in_specs=(xspec, P(None, None),
+                                P("model", "data", None),
+                                P("model", None, "data")),
+                      out_specs=xspec, check_vma=False)
     return f(x, p["router"], p["wi"], p["wo"])
 
 

@@ -289,8 +289,9 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
 from repro.fabric import MeshTransport
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((4,), ("data",))
+mesh = make_mesh((4,), ("data",))
 n, cap, A = 4, 12, 40
 rng = np.random.default_rng(0)
 fields = {"k": jnp.asarray(rng.integers(0, 99, (A,)), jnp.uint32),

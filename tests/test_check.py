@@ -24,6 +24,7 @@ import numpy as np
 import pytest
 
 from repro.fabric import LocalTransport, check
+from repro.launch.mesh import make_mesh
 
 LOCK = 1 << 31
 
@@ -47,15 +48,14 @@ def test_walker_attributes_primitives_inside_scan():
 
 def test_collective_budget_exact_counts():
     import jax.numpy as _  # noqa: F401
-    mesh = jax.make_mesh((1,), ("ax",))
-    from jax.experimental.shard_map import shard_map
+    mesh = make_mesh((1,), ("ax",))
     from jax.sharding import PartitionSpec as P
 
     def body(v):
         return jax.lax.all_to_all(v.reshape(1, -1), "ax", 0, 0).reshape(-1)
 
-    f = shard_map(body, mesh=mesh, in_specs=P("ax"), out_specs=P("ax"),
-                  check_rep=False)
+    f = jax.shard_map(body, mesh=mesh, in_specs=P("ax"), out_specs=P("ax"),
+                      check_vma=False)
     jaxpr = jax.make_jaxpr(f)(jnp.zeros((4,), jnp.uint32))
     ok = check.lint_jaxpr(jaxpr, [check.CollectiveBudget(
         {"all_to_all": 1})], target="one")
@@ -80,7 +80,7 @@ def test_no_host_transfer_flags_callbacks():
 
 
 def test_packed_wire_flags_non_u32_collective():
-    mesh = jax.make_mesh((1,), ("ax",))
+    mesh = make_mesh((1,), ("ax",))
     from repro.fabric import MeshTransport
     tp = MeshTransport(mesh, "ax")
     # an f32 buffer on the exchange bypasses the packed u32 wire

@@ -7,6 +7,7 @@ import pytest
 
 from repro.core import aggregation, bloom, costmodel, shuffle
 from repro.fabric import LocalTransport, MeshTransport
+from repro.launch.mesh import make_mesh
 
 
 @pytest.fixture(scope="module")
@@ -31,7 +32,7 @@ def test_local_join_variants_agree(rel):
 
 def test_distributed_join_one_shard(rel):
     rk, rv, sk, sv, expect = rel
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     for transport in (LocalTransport(), MeshTransport(mesh, "data")):
         for variant in ("ghj", "ghj_bloom", "rdma_ghj", "rrj"):
             f = shuffle.make_distributed_join(transport, variant)
@@ -49,7 +50,7 @@ def test_bloom_no_false_negatives():
 
 def test_aggregation_schemes_agree():
     key = jax.random.PRNGKey(1)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     for transport in (LocalTransport(), MeshTransport(mesh, "data")):
         for groups in (4, 64, 512):
             keys = jax.random.randint(key, (4096,), 0, 100_000

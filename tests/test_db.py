@@ -10,6 +10,7 @@ from repro.core import costmodel, rsi
 from repro.db import (AGG_VARIANTS, JOIN_VARIANTS, Database, Planner,
                       Session, Table)
 from repro.fabric import LocalTransport, MeshTransport
+from repro.launch.mesh import make_mesh
 
 
 # ------------------------------------------------------------- planner ----
@@ -109,7 +110,7 @@ def test_session_commit_parity_with_raw_rsi(transport_kind):
     oracle assigns the same contiguous cids the raw batch uses)."""
     nrec, recs, pay, ok_raw, st_raw = _parity_fixture()
     tp = (LocalTransport() if transport_kind == "local" else
-          MeshTransport(jax.make_mesh((1,), ("data",)), "data"))
+          MeshTransport(make_mesh((1,), ("data",)), "data"))
     db = Database(transport=tp)
     t = db.create_table("t", nrec, payload_words=2, num_timestamps=64)
     t.seed(np.arange(nrec))

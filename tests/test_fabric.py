@@ -10,6 +10,7 @@ from repro import fabric
 from repro.core import rsi, shuffle, workqueue
 from repro.core.rsi import StoreCfg, TxnBatch
 from repro.fabric import LocalTransport, MeshTransport
+from repro.launch.mesh import make_mesh
 
 
 # --------------------------------------------------------------- router ---
@@ -68,7 +69,7 @@ def test_route_chunks_equivalence(transport_kind):
     if transport_kind == "local":
         tp = LocalTransport()
     else:
-        tp = MeshTransport(jax.make_mesh((1,), ("data",)), "data")
+        tp = MeshTransport(make_mesh((1,), ("data",)), "data")
     key = jax.random.PRNGKey(0)
     vals = jax.random.randint(key, (64,), 0, 1000).astype(jnp.int32)
     dest = jax.random.randint(jax.random.fold_in(key, 1), (64,), 0,
@@ -168,7 +169,7 @@ def test_commit_local_vs_mesh_parity():
     store["cids"] = store["cids"].at[:, 0].set(1)
     txns = _mk_batch()
     ok_l, st_l = rsi.commit(store, txns, transport=LocalTransport())
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     ok_m, st_m = rsi.commit(store, txns,
                             transport=MeshTransport(mesh, "data"))
     np.testing.assert_array_equal(np.array(ok_l), np.array(ok_m))

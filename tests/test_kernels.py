@@ -10,38 +10,47 @@ from hypothesis import given, settings, strategies as st
 from repro.kernels import ops, ref
 
 
-@pytest.mark.parametrize("n,d,p,cap,dtype", [
-    (256, 8, 4, 128, jnp.float32),
-    (512, 16, 8, 96, jnp.float32),
-    (256, 32, 2, 256, jnp.bfloat16),
+@pytest.mark.parametrize("a,w,s", [
+    (256, 2, 512),       # join shuffle rows, half-empty wire buffer
+    (512, 16, 384),      # more rows than slots
+    (300, 259, 256),     # RSI install rows: 256 payload words + 3
+    (4, 600, 4),         # aggregation flush: few wide rows
 ])
-def test_radix_partition_sweep(n, d, p, cap, dtype):
-    key = jax.random.PRNGKey(n + d)
-    vals = jax.random.normal(key, (n, d), jnp.float32).astype(dtype)
-    bucket = jax.random.randint(key, (n,), 0, p)
-    o1, c1 = ops.radix_partition(vals, bucket, p, cap, block_n=128)
-    o2, c2 = ref.radix_partition(vals, bucket, p, cap)
-    np.testing.assert_allclose(np.asarray(o1, np.float32),
-                               np.asarray(o2, np.float32))
-    np.testing.assert_array_equal(c1, c2)
+def test_radix_partition_sweep(a, w, s):
+    """Kernel == gather oracle, bit for bit: every slot takes its source
+    row plus a valid lane, empty slots stay all-zero."""
+    rng = np.random.default_rng(a + w + s)
+    rows = jnp.asarray(rng.integers(0, 1 << 32, (a, w), dtype=np.uint64)
+                       .astype(np.uint32))
+    src = np.full(s, a, np.int32)
+    k = min(a, s) * 3 // 4
+    src[np.sort(rng.choice(s, k, replace=False))] = np.sort(
+        rng.choice(a, k, replace=False))
+    got = ops.radix_partition(rows, jnp.asarray(src))
+    want = ref.radix_partition(rows, jnp.asarray(src))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 @settings(max_examples=10, deadline=None)
 @given(st.integers(2, 7), st.integers(1, 3))
 def test_radix_partition_property(num_buckets, seed):
-    """Every kept row appears in its bucket, in stable order, up to cap."""
+    """Through the router's Pallas backend, every kept row appears in its
+    bucket, in stable order, up to cap."""
+    from repro.fabric import router
     key = jax.random.PRNGKey(seed)
     n, cap = 128, 32
     vals = jnp.arange(n, dtype=jnp.float32)[:, None] * jnp.ones((1, 4))
     bucket = jax.random.randint(key, (n,), 0, num_buckets)
-    out, counts = ops.radix_partition(vals, bucket, num_buckets, cap,
-                                      block_n=64)
+    res = router.route({"v": vals}, bucket, n=num_buckets, cap=cap,
+                       backend="pallas")
+    out = np.array(res.fields["v"]).reshape(num_buckets, cap, 4)
+    valid = np.array(res.valid).reshape(num_buckets, cap)
     bucket = np.array(bucket)
-    out = np.array(out)
     for b in range(num_buckets):
         rows = np.nonzero(bucket == b)[0][:cap]
-        got = out[b, :len(rows), 0]
-        np.testing.assert_array_equal(got, rows.astype(np.float32))
+        np.testing.assert_array_equal(out[b, :len(rows), 0],
+                                      rows.astype(np.float32))
+        assert valid[b, :len(rows)].all() and not valid[b, len(rows):].any()
 
 
 @pytest.mark.parametrize("s,t,h,kh,d,causal,dtype", [

@@ -19,6 +19,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
+from repro.launch.mesh import make_mesh
 
 mode = os.environ["MD_MODE"]
 
@@ -29,7 +30,7 @@ if mode == "moe":
     import dataclasses
     cfg = reduce_config(get_config("deepseek-v2-236b"))
     mcfg = dataclasses.replace(cfg.moe, capacity_factor=8.0)  # no drops
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     key = jax.random.PRNGKey(0)
     mk_key = jax.random.fold_in(key, 1)
     E, D, F = mcfg.num_experts, cfg.d_model, mcfg.d_ff
@@ -59,7 +60,7 @@ elif mode == "rsi":
     from repro.db import Database
     from repro.fabric import MeshTransport
     nrec, nsh = 32, 8
-    mesh = jax.make_mesh((nsh,), ("data",))
+    mesh = make_mesh((nsh,), ("data",))
     cfg = StoreCfg(num_records=nrec, payload_words=2, version_slots=1,
                    num_timestamps=64)
     store = rsi.init_store(cfg)
@@ -97,7 +98,7 @@ elif mode == "rsi":
 elif mode == "olap":
     from repro.core import shuffle, aggregation
     from repro.fabric import MeshTransport
-    mesh4 = jax.make_mesh((4,), ("data",))
+    mesh4 = make_mesh((4,), ("data",))
     tp4 = MeshTransport(mesh4, "data")
     key = jax.random.PRNGKey(0)
     rk = jax.random.permutation(key, jnp.arange(1, 2049, dtype=jnp.uint32))
@@ -125,7 +126,7 @@ elif mode == "dryrun":
     from repro.train import train_step as ts
     from repro.train.optimizer import make_optimizer
     cfg = reduce_config(get_config("glm4-9b"))
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     pol = make_policy(mesh, shape_kind="train")
     with mesh, set_policy(pol):
         pshapes = jax.eval_shape(

@@ -12,6 +12,7 @@ import pytest
 from repro.checkpoint import CheckpointManager
 from repro.core.workqueue import WorkQueue, run_workers
 from repro.data import SyntheticLM, Prefetcher
+from repro.launch.mesh import make_mesh
 from repro.sharding import make_policy, param_pspec
 from repro.train.optimizer import (clip_by_global_norm, make_adafactor,
                                    make_adamw)
@@ -87,7 +88,7 @@ def test_checkpoint_elastic_resharding(tmp_path):
     mgr = CheckpointManager(str(tmp_path))
     tree = {"w": jnp.arange(64.0).reshape(8, 8)}
     mgr.save(1, tree)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     from jax.sharding import NamedSharding, PartitionSpec as P
     sh = {"w": NamedSharding(mesh, P("data", None))}
     got, _ = mgr.restore(tree, shardings=sh)
@@ -140,7 +141,7 @@ def test_prefetcher():
 # ------------------------------------------------------------- sharding ---
 
 def test_policy_resolution():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     pol = make_policy(mesh, shape_kind="train")
     assert pol.resolve(("batch", "seq_sharded", None))[1] == "model"
     dec = make_policy(mesh, shape_kind="decode")

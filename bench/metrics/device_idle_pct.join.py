@@ -1,0 +1,5 @@
+"""Share of the join window in which the chip ran no op, in %."""
+
+
+def read(ctx):
+    return ctx.trace.idle_pct() if ctx.trace.busy_s() > 0 else None

@@ -18,6 +18,7 @@ ground truth, ``MeshTransport(mesh, axis)`` for the real collectives).
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -40,18 +41,29 @@ def preagg_table(keys, vals, table_slots: int):
     return table
 
 
+def _program(name: str, transport, body):
+    """f(keys, vals) running ``body`` over the transport, named ``name``
+    (its jitted program is ``jit_<name>``)."""
+    def f(keys, vals):
+        return transport.run(body, (keys, vals), out_reps=True)
+
+    f.__name__ = name
+    return f
+
+
 def dist_agg(transport, num_groups: int):
     """Classic hierarchical aggregation. Inputs sharded on axis 0.
     Returns f(keys, vals) -> dense (num_groups,) sums (group = key hash)."""
 
     def body(keys, vals):
-        local = segment_sum_by_key(keys, vals, num_groups)    # phase 1
+        with jax.named_scope("agg.local"):
+            local = segment_sum_by_key(keys, vals, num_groups)  # phase 1
         # global union + post-aggregation on every node (paper: the union
         # output is #nodes x #groups rows)
-        return transport.psum(local)                          # phase 2
+        with jax.named_scope("agg.merge"):
+            return transport.psum(local)                        # phase 2
 
-    return lambda keys, vals: transport.run(body, (keys, vals),
-                                            out_reps=True)
+    return _program("agg_dist_agg", transport, body)
 
 
 def rdma_agg(transport, num_groups: int, *, table_slots: int = 4096,
@@ -67,25 +79,29 @@ def rdma_agg(transport, num_groups: int, *, table_slots: int = 4096,
     def body(keys, vals):
         gsz = max(num_groups // n, 1)
         N = keys.shape[0]
-        # phase 1: per-chunk cache-sized pre-aggregation into the owner
-        # layout — one (n, gsz) partition table per chunk
-        ck = keys.reshape(chunks, N // chunks)
-        cv = vals.reshape(chunks, N // chunks)
-        slot = (ck % jnp.uint32(num_groups)).astype(jnp.int32)
-        owner = jnp.minimum(slot // gsz, n - 1)
-        ci = jnp.broadcast_to(
-            jnp.arange(chunks, dtype=jnp.int32)[:, None], slot.shape)
-        part = jnp.zeros((chunks, n, gsz), jnp.uint64).at[
-            ci, owner, slot % gsz].add(cv.astype(jnp.uint64))
-        # background flush: route each chunk's n owner tables (dest = owner,
-        # cap = chunks, chunked exchange pipelines the transfer)
-        tabs = part.reshape(chunks * n, gsz)
-        dest = jnp.tile(jnp.arange(n, dtype=jnp.int32), chunks)
-        res = transport.route({"tab": tabs}, dest, cap=chunks, chunks=chunks)
-        # phase 2: parallel post-aggregation of my slice only
-        mine = jnp.sum(res.fields["tab"]
-                       * (res.valid > 0).astype(jnp.uint64)[:, None], axis=0)
-        return transport.all_gather(mine)[:num_groups]
+        with jax.named_scope("agg.local"):
+            # phase 1: per-chunk cache-sized pre-aggregation into the
+            # owner layout — one (n, gsz) partition table per chunk
+            ck = keys.reshape(chunks, N // chunks)
+            cv = vals.reshape(chunks, N // chunks)
+            slot = (ck % jnp.uint32(num_groups)).astype(jnp.int32)
+            owner = jnp.minimum(slot // gsz, n - 1)
+            ci = jnp.broadcast_to(
+                jnp.arange(chunks, dtype=jnp.int32)[:, None], slot.shape)
+            part = jnp.zeros((chunks, n, gsz), jnp.uint64).at[
+                ci, owner, slot % gsz].add(cv.astype(jnp.uint64))
+        with jax.named_scope("agg.route"):
+            # background flush: route each chunk's n owner tables (dest =
+            # owner, cap = chunks, chunked exchange pipelines the transfer)
+            tabs = part.reshape(chunks * n, gsz)
+            dest = jnp.tile(jnp.arange(n, dtype=jnp.int32), chunks)
+            res = transport.route({"tab": tabs}, dest, cap=chunks,
+                                  chunks=chunks)
+        with jax.named_scope("agg.merge"):
+            # phase 2: parallel post-aggregation of my slice only
+            mine = jnp.sum(res.fields["tab"]
+                           * (res.valid > 0).astype(jnp.uint64)[:, None],
+                           axis=0)
+            return transport.all_gather(mine)[:num_groups]
 
-    return lambda keys, vals: transport.run(body, (keys, vals),
-                                            out_reps=True)
+    return _program("agg_rdma_agg", transport, body)

@@ -122,91 +122,95 @@ def commit(store, txns: TxnBatch, *, transport=None, priority=None,
         me = transport.shard_index()
         r_local = words.shape[0]       # records per home shard (contiguous)
         bv_local = bitvec.shape[0]
-        # ---- route prepares to home shards (radix by rec // r_local);
-        # unused write slots are filtered (dest = n), not dropped.
-        dest = jnp.where(wrecs >= 0, wrecs // r_local, n)
-        flat_dest = dest.reshape(-1)
-        cap = Tl * W  # worst case: all my writes hit one shard
-        gid = jnp.repeat(prio, W)      # globally unique txn priority
-        recs_flat = wrecs.reshape(-1)
-        exp_flat = (rcids & CID_MASK).reshape(-1)
-        cid_flat = jnp.repeat(cid & CID_MASK, W)
-        npay_flat = npay.reshape(Tl * W, -1)
-        # the CAS prepare is payload-free (paper msg 2): new CIDs and
-        # payloads stay client-side until the install round trip
-        req = {"rec": recs_flat, "exp": exp_flat, "prio": gid,
-               "slot": jnp.arange(Tl * W, dtype=jnp.int32)}
-        # both rounds travel to the same home shards, so the slot
-        # assignment is binned ONCE and reused for the install (the act
-        # filter is a mask over the same plan — slots stay put, which is
-        # also what keeps the response path stable)
-        plan = transport.plan_route(flat_dest, cap=cap)
-        res = transport.route(req, plan=plan, chunks=chunks)
-        r, rvalid = res.fields, res.valid
-        # ---- local CAS arbitration on my records (global prio = fair)
-        lrec = jnp.where(rvalid > 0, r["rec"] % r_local, -1)  # local row
-        ok, words = transport.cas(words, lrec, r["exp"],
-                                  LOCK_BIT | r["exp"], priority=r["prio"],
-                                  region=region_ns + "words")
-        # ---- grants return to requesters (paired reverse exchange lands
-        # each response in the slot it was sent from); the grant bit
-        # crosses the collective in the packed u32 wire width
-        grant = transport.exchange(ok.astype(jnp.uint32),
-                                   exchange_chunks).astype(jnp.int32)
-        granted = jnp.zeros((Tl * W,), jnp.int32).at[res.sent["slot"]].add(
-            grant * res.sent_valid)
-        gmat = granted.reshape(Tl, W) > 0
-        used = wrecs >= 0
-        txn_ok = jnp.all(gmat | ~used, axis=1) & jnp.any(used, axis=1)
-        # ---- phase 2: installs routed the same way (write + unlock);
-        # committed txns install their CID, losers restore the old word.
-        commit_req = jnp.repeat(txn_ok, W) & (granted > 0)
-        release_req = (granted > 0) & ~commit_req
-        inst = {"rec": recs_flat,
-                "val": jnp.where(commit_req, cid_flat, exp_flat),
-                "npay": npay_flat,
-                "do_pay": commit_req.astype(jnp.int32)}
-        act = commit_req | release_req
-        res2 = transport.route(inst, plan=plan, mask=act, chunks=chunks)
-        r2, v2 = res2.fields, res2.valid
-        lrec2 = jnp.where(v2 > 0, r2["rec"] % r_local, -1)
-        words = transport.write(words, lrec2, r2["val"],
-                                region=region_ns + "words")
-        # version install: shift slots left, newest at 0.
-        # NB: negative indices WRAP in jnp scatters — use an explicit OOB
-        # sentinel (row N) so mode="drop" actually drops skipped writes.
-        oob = payload.shape[0]
-        pay_idx = jnp.where((r2["do_pay"] > 0) & (v2 > 0), lrec2, -1)
-        idx_pay = jnp.where(pay_idx >= 0, pay_idx, oob)
-        if payload.shape[1] > 1:
-            shifted_pay = jnp.concatenate(
-                [payload[:, :1], payload[:, :-1]], axis=1)
-            shifted_cid = jnp.concatenate(
-                [cids[:, :1], cids[:, :-1]], axis=1)
-            has_commit = jnp.zeros((oob,), bool).at[idx_pay].set(
-                True, mode="drop")
-            payload = jnp.where(has_commit[:, None, None], shifted_pay,
-                                payload)
-            cids = jnp.where(has_commit[:, None], shifted_cid, cids)
-        payload = payload.at[idx_pay, 0].set(r2["npay"], mode="drop")
-        cids = cids.at[idx_pay, 0].set(r2["val"], mode="drop")
-        # install bytes are already billed to the routed buffer; the
-        # scatter itself is invisible to the verbs, so log it record-only
-        # for the race detector's lock-protocol / conflict rules
-        transport.record_access("WRITE", region_ns + "payload", pay_idx,
-                                region_len=oob)
-        transport.record_access("WRITE", region_ns + "cids", pay_idx,
-                                region_len=oob)
-        # ---- timestamp bitvector [msg 3, unsignaled]: clients flip their
-        # own (locally owned) bits; aborted txns also burn their slot (the
-        # paper's wrap/skip bookkeeping). cids are pre-assigned in shard-
-        # contiguous ranges [me*bv_local, ...).
-        cbit = cid.astype(jnp.int32) - me * bv_local
-        cbit = jnp.where((cbit >= 0) & (cbit < bv_local), cbit, bv_local)
-        bitvec = bitvec.at[cbit].set(True, mode="drop")
-        transport.record_access(
-            "WRITE", region_ns + "bitvec",
-            jnp.where(cbit < bv_local, cbit, -1), region_len=bv_local)
+        with jax.named_scope("rsi.prepare"):
+            # ---- route prepares to home shards (radix by rec // r_local);
+            # unused write slots are filtered (dest = n), not dropped.
+            dest = jnp.where(wrecs >= 0, wrecs // r_local, n)
+            flat_dest = dest.reshape(-1)
+            cap = Tl * W  # worst case: all my writes hit one shard
+            gid = jnp.repeat(prio, W)      # globally unique txn priority
+            recs_flat = wrecs.reshape(-1)
+            exp_flat = (rcids & CID_MASK).reshape(-1)
+            cid_flat = jnp.repeat(cid & CID_MASK, W)
+            npay_flat = npay.reshape(Tl * W, -1)
+            # the CAS prepare is payload-free (paper msg 2): new CIDs and
+            # payloads stay client-side until the install round trip
+            req = {"rec": recs_flat, "exp": exp_flat, "prio": gid,
+                   "slot": jnp.arange(Tl * W, dtype=jnp.int32)}
+            # both rounds travel to the same home shards, so the slot
+            # assignment is binned ONCE and reused for the install (the act
+            # filter is a mask over the same plan — slots stay put, which is
+            # also what keeps the response path stable)
+            plan = transport.plan_route(flat_dest, cap=cap)
+            res = transport.route(req, plan=plan, chunks=chunks)
+            r, rvalid = res.fields, res.valid
+        with jax.named_scope("rsi.grant"):
+            # ---- local CAS arbitration on my records (global prio = fair)
+            lrec = jnp.where(rvalid > 0, r["rec"] % r_local, -1)  # local row
+            ok, words = transport.cas(words, lrec, r["exp"],
+                                      LOCK_BIT | r["exp"], priority=r["prio"],
+                                      region=region_ns + "words")
+            # ---- grants return to requesters (paired reverse exchange lands
+            # each response in the slot it was sent from); the grant bit
+            # crosses the collective in the packed u32 wire width
+            grant = transport.exchange(ok.astype(jnp.uint32),
+                                       exchange_chunks).astype(jnp.int32)
+            granted = jnp.zeros((Tl * W,), jnp.int32).at[res.sent["slot"]].add(
+                grant * res.sent_valid)
+            gmat = granted.reshape(Tl, W) > 0
+            used = wrecs >= 0
+            txn_ok = jnp.all(gmat | ~used, axis=1) & jnp.any(used, axis=1)
+        with jax.named_scope("rsi.install"):
+            # ---- phase 2: installs routed the same way (write + unlock);
+            # committed txns install their CID, losers restore the old word.
+            commit_req = jnp.repeat(txn_ok, W) & (granted > 0)
+            release_req = (granted > 0) & ~commit_req
+            inst = {"rec": recs_flat,
+                    "val": jnp.where(commit_req, cid_flat, exp_flat),
+                    "npay": npay_flat,
+                    "do_pay": commit_req.astype(jnp.int32)}
+            act = commit_req | release_req
+            res2 = transport.route(inst, plan=plan, mask=act, chunks=chunks)
+            r2, v2 = res2.fields, res2.valid
+            lrec2 = jnp.where(v2 > 0, r2["rec"] % r_local, -1)
+            words = transport.write(words, lrec2, r2["val"],
+                                    region=region_ns + "words")
+            # version install: shift slots left, newest at 0.
+            # NB: negative indices WRAP in jnp scatters — use an explicit OOB
+            # sentinel (row N) so mode="drop" actually drops skipped writes.
+            oob = payload.shape[0]
+            pay_idx = jnp.where((r2["do_pay"] > 0) & (v2 > 0), lrec2, -1)
+            idx_pay = jnp.where(pay_idx >= 0, pay_idx, oob)
+            if payload.shape[1] > 1:
+                shifted_pay = jnp.concatenate(
+                    [payload[:, :1], payload[:, :-1]], axis=1)
+                shifted_cid = jnp.concatenate(
+                    [cids[:, :1], cids[:, :-1]], axis=1)
+                has_commit = jnp.zeros((oob,), bool).at[idx_pay].set(
+                    True, mode="drop")
+                payload = jnp.where(has_commit[:, None, None], shifted_pay,
+                                    payload)
+                cids = jnp.where(has_commit[:, None], shifted_cid, cids)
+            payload = payload.at[idx_pay, 0].set(r2["npay"], mode="drop")
+            cids = cids.at[idx_pay, 0].set(r2["val"], mode="drop")
+            # install bytes are already billed to the routed buffer; the
+            # scatter itself is invisible to the verbs, so log it record-only
+            # for the race detector's lock-protocol / conflict rules
+            transport.record_access("WRITE", region_ns + "payload", pay_idx,
+                                    region_len=oob)
+            transport.record_access("WRITE", region_ns + "cids", pay_idx,
+                                    region_len=oob)
+        with jax.named_scope("rsi.bitvec"):
+            # ---- timestamp bitvector [msg 3, unsignaled]: clients flip their
+            # own (locally owned) bits; aborted txns also burn their slot (the
+            # paper's wrap/skip bookkeeping). cids are pre-assigned in shard-
+            # contiguous ranges [me*bv_local, ...).
+            cbit = cid.astype(jnp.int32) - me * bv_local
+            cbit = jnp.where((cbit >= 0) & (cbit < bv_local), cbit, bv_local)
+            bitvec = bitvec.at[cbit].set(True, mode="drop")
+            transport.record_access(
+                "WRITE", region_ns + "bitvec",
+                jnp.where(cbit < bv_local, cbit, -1), region_len=bv_local)
         return txn_ok, words, payload, cids, bitvec
 
     txn_ok, words, payload, cids, bitvec = transport.run(

@@ -19,6 +19,7 @@ Relations are (keys, values) u32/u32; R is the (unique-key) build side.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import bloom as bloom_mod
@@ -38,12 +39,14 @@ def radix_partition(keys, num_parts: int, *, bits_from: int = 0):
 def local_join(rk, rv, sk, sv):
     """Join unique-key build side R with probe side S.
     Returns (matched mask (|S|,), r-values aligned to S (|S|,))."""
-    order = jnp.argsort(rk)
-    rks, rvs = rk[order], rv[order]
-    pos = jnp.searchsorted(rks, sk)
-    pos = jnp.clip(pos, 0, rks.shape[0] - 1)
-    hit = rks[pos] == sk
-    return hit, jnp.where(hit, rvs[pos], 0)
+    with jax.named_scope("join.sort"):
+        order = jnp.argsort(rk)
+        rks, rvs = rk[order], rv[order]
+    with jax.named_scope("join.probe"):
+        pos = jnp.searchsorted(rks, sk)
+        pos = jnp.clip(pos, 0, rks.shape[0] - 1)
+        hit = rks[pos] == sk
+        return hit, jnp.where(hit, rvs[pos], 0)
 
 
 def _cache_blocks(keys, vals, num_blocks):
@@ -69,10 +72,11 @@ def ghj_local(rk, rv, sk, sv, *, num_parts: int = 32,
         keep = bloom_mod.query(bits, sk)
         # fixed-shape filter: drop misses by pointing them at a sentinel key
         sk = jnp.where(keep, sk, MISS)
-    _, orderR, _ = radix_partition(rk, num_parts)
-    _, orderS, _ = radix_partition(sk, num_parts)
-    rk2, rv2 = _cache_blocks(rk[orderR], rv[orderR], num_parts)
-    sk2, sv2 = _cache_blocks(sk[orderS], sv[orderS], num_parts)
+    with jax.named_scope("join.radix"):
+        _, orderR, _ = radix_partition(rk, num_parts)
+        _, orderS, _ = radix_partition(sk, num_parts)
+        rk2, rv2 = _cache_blocks(rk[orderR], rv[orderR], num_parts)
+        sk2, sv2 = _cache_blocks(sk[orderS], sv[orderS], num_parts)
     hit, rvals = local_join(rk2, rv2, sk2, sv2)
     return join_agg(hit, rvals, sv2)
 
@@ -80,9 +84,11 @@ def ghj_local(rk, rv, sk, sv, *, num_parts: int = 32,
 def rrj_local(rk, rv, sk, sv, *, num_blocks: int = 64):
     """RRJ collapses GHJ's network partition + radix pass into ONE radix pass
     straight into cache-sized remote buffers (paper §5.2)."""
-    _, orderR, _ = radix_partition(rk, num_blocks)
-    _, orderS, _ = radix_partition(sk, num_blocks)
-    hit, rvals = local_join(rk[orderR], rv[orderR], sk[orderS], sv[orderS])
+    with jax.named_scope("join.radix"):
+        _, orderR, _ = radix_partition(rk, num_blocks)
+        _, orderS, _ = radix_partition(sk, num_blocks)
+        blocks = rk[orderR], rv[orderR], sk[orderS], sv[orderS]
+    hit, rvals = local_join(*blocks)
     return join_agg(hit, rvals, sv[orderS])
 
 
@@ -93,12 +99,13 @@ def _route_by_key(transport, keys, vals, cap: int, chunks: int = 1):
     router; MISS keys are filtered, empty slots come back as MISS.
     Returns (keys, vals, dropped) — dropped = rows lost to cap overflow."""
     n = transport.n
-    dest = (keys % jnp.uint32(n)).astype(jnp.int32)
-    dest = jnp.where(keys == MISS, n, dest)        # filtered, not dropped
-    res = transport.route({"k": keys, "v": vals}, dest, cap=cap,
-                          chunks=chunks)
-    k = jnp.where(res.valid > 0, res.fields["k"], MISS)
-    return k, res.fields["v"], res.dropped
+    with jax.named_scope("join.route"):
+        dest = (keys % jnp.uint32(n)).astype(jnp.int32)
+        dest = jnp.where(keys == MISS, n, dest)    # filtered, not dropped
+        res = transport.route({"k": keys, "v": vals}, dest, cap=cap,
+                              chunks=chunks)
+        k = jnp.where(res.valid > 0, res.fields["k"], MISS)
+        return k, res.fields["v"], res.dropped
 
 
 def make_distributed_join(transport, variant: str, *,
@@ -142,4 +149,5 @@ def make_distributed_join(transport, variant: str, *,
                                      out_reps=(True, True))
         return (agg, dropped) if return_stats else agg
 
+    f.__name__ = f"join_{variant}"     # the jitted program's name
     return f

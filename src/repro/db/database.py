@@ -23,6 +23,7 @@ alternative.
 """
 from __future__ import annotations
 
+import itertools
 import time
 from dataclasses import dataclass, field
 from typing import List, Optional
@@ -31,7 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import fabric
+from repro import fabric, obs
 from repro.core import aggregation, rsi, shuffle, twopc
 from repro.db.plan import Plan
 from repro.db.planner import Planner
@@ -74,9 +75,9 @@ class QueryResult:
     variant: str                        # strategy that actually ran
     alternatives: tuple                 # costed Alternatives, argmin first
     plan: Plan
-    elapsed_s: float
     stats: dict = field(default_factory=dict)   # fabric counter delta
-                                                # (trace-time; empty on
+                                                # (calibrate=True only;
+                                                # trace-time, empty on
                                                 # cached re-execution)
 
     @property
@@ -128,6 +129,11 @@ class Database:
         self.pool.alloc("oracle/clock", (1,), jnp.uint32, ("replicated",))
         self._clock = jnp.full((1,), 2, jnp.uint32)
         self._jit_cache: dict = {}
+        # request numbers: the ids of the spans of each commit wave,
+        # query and session (repro.obs)
+        self._waves = itertools.count()
+        self._queries = itertools.count()
+        self._sessions = itertools.count()
         # per-txn outcome economics (commit/abort/retry counts — the
         # contention side of the ledger the wire counters can't see)
         self.txn_stats = {"commits": 0, "aborts": 0, "retries": 0,
@@ -166,15 +172,19 @@ class Database:
     def claim_cids(self, k: int) -> np.ndarray:
         """Claim k commit timestamps with one FETCH_ADD on the oracle word
         (every client bumps the same counter — §3.2's decentralized pull)."""
-        fetched, self._clock = self.transport.fetch_add(
-            self._clock, jnp.zeros((k,), jnp.int32),
-            jnp.ones((k,), jnp.uint32), region="oracle/clock")
-        return np.asarray(fetched, np.uint32)
+        with obs.span("namdb.sync.claim"):
+            fetched, self._clock = self.transport.fetch_add(
+                self._clock, jnp.zeros((k,), jnp.int32),
+                jnp.ones((k,), jnp.uint32), region="oracle/clock")
+            return np.asarray(fetched, np.uint32)
 
-    def read_timestamp(self) -> int:
+    def read_timestamp(self, **ids) -> int:
         """Current read snapshot: every cid below the clock is decided
-        (committed or burned — RSI aborts consume their slot too)."""
-        return int(self._clock[0]) - 1
+        (committed or burned — RSI aborts consume their slot too).
+        ``ids`` tag its ``namdb.sync.oracle`` span (``Session.begin``
+        passes ``session``)."""
+        with obs.span("namdb.sync.oracle", **ids):
+            return int(self._clock[0]) - 1
 
     # ---------------------------------------------------------- sessions --
 
@@ -186,11 +196,13 @@ class Database:
         shape): newest version with CID <= rid (default: the oracle's
         current read timestamp), as counted one-sided READs.
         Returns (payload, read_cids, ok)."""
-        t = self.table(table)
-        rid = self.read_timestamp() if rid is None else int(rid)
-        return rsi.read_snapshot(t.store, jnp.asarray(recs, jnp.int32),
-                                 jnp.uint32(rid), transport=self.transport,
-                                 region_ns=f"{t.schema.name}/")
+        with obs.span("namdb.snapshot_read"):
+            t = self.table(table)
+            rid = self.read_timestamp() if rid is None else int(rid)
+            return rsi.read_snapshot(t.store, jnp.asarray(recs, jnp.int32),
+                                     jnp.uint32(rid),
+                                     transport=self.transport,
+                                     region_ns=f"{t.schema.name}/")
 
     def commit(self, sessions: List[Session], *, chunks: int = 1,
                priority=None, max_retries: int = 0) -> np.ndarray:
@@ -208,8 +220,10 @@ class Database:
         ``txn_stats`` / the ``"txn"`` entry of :meth:`fabric_stats`."""
         if not sessions:
             return np.zeros((0,), bool)
-        self._commit_wave(sessions, chunks=chunks, priority=priority)
-        self._retry_losers(sessions, chunks=chunks, max_retries=max_retries)
+        with obs.span("namdb.commit", wave=next(self._waves)):
+            self._commit_wave(sessions, chunks=chunks, priority=priority)
+            self._retry_losers(sessions, chunks=chunks,
+                               max_retries=max_retries)
         return np.asarray([bool(s.committed) for s in sessions], bool)
 
     def _commit_wave(self, sessions: List[Session], *, chunks: int = 1,
@@ -238,7 +252,8 @@ class Database:
             t.store, txns,
             None if priority is None else jnp.asarray(priority, jnp.int32))
         self._complete_bitvec(t, cids)
-        ok = np.asarray(ok)
+        with obs.span("namdb.sync.outcome"):
+            ok = np.asarray(ok)
         self._assign_outcomes(sessions, ok, cids)
         return np.asarray([s.committed for s in wave], bool)
 
@@ -279,19 +294,21 @@ class Database:
         self.txn_stats["aborts"] += len(losers)
         attempt = 1
         while losers and attempt <= max_retries:
-            self._backoff(losers, attempt)
-            self._refresh_losers(losers)
-            self.txn_stats["retries"] += len(losers)
-            # dyadic chunking: retry waves run in power-of-two sizes so
-            # the whole sweep's wave shapes form a tiny closed set and the
-            # jit / eager op caches stay warm (loser counts are otherwise
-            # all distinct).  A chunk-2 txn that loses a row to chunk 1
-            # just fails validation and burns this attempt — the same
-            # bounded-retry semantics, one round later.
-            for chunk in _dyadic(losers):
-                self._commit_wave(chunk, chunks=chunks)
-            losers = [s for s in losers if not s.committed]
-            self.txn_stats["aborts"] += len(losers)
+            with obs.span("namdb.commit.retry", attempt=attempt):
+                self._backoff(losers, attempt)
+                self._refresh_losers(losers)
+                self.txn_stats["retries"] += len(losers)
+                # dyadic chunking: retry waves run in power-of-two sizes
+                # so the whole sweep's wave shapes form a tiny closed set
+                # and the jit / eager op caches stay warm (loser counts
+                # are otherwise all distinct).  A chunk-2 txn that loses
+                # a row to chunk 1 just fails validation and burns this
+                # attempt — the same bounded-retry semantics, one round
+                # later.
+                for chunk in _dyadic(losers):
+                    self._commit_wave(chunk, chunks=chunks)
+                losers = [s for s in losers if not s.committed]
+                self.txn_stats["aborts"] += len(losers)
             attempt += 1
         self.txn_stats["commits"] += sum(bool(s.committed) for s in sessions)
 
@@ -306,10 +323,13 @@ class Database:
         :meth:`Session.refresh_read_cids`."""
         t = self.table(losers[0].table_name)
         per = [np.concatenate(s._recs) for s in losers]
-        words = self.transport.read(
-            t.store["words"], jnp.asarray(np.concatenate(per), jnp.int32),
-            region=f"{t.schema.name}/words")
-        fresh = np.asarray(words, np.uint32) & np.uint32(int(rsi.CID_MASK))
+        with obs.span("namdb.sync.refresh"):
+            words = self.transport.read(
+                t.store["words"],
+                jnp.asarray(np.concatenate(per), jnp.int32),
+                region=f"{t.schema.name}/words")
+            fresh = (np.asarray(words, np.uint32)
+                     & np.uint32(int(rsi.CID_MASK)))
         rid = self.read_timestamp()
         off = 0
         for s, recs in zip(losers, per):
@@ -369,7 +389,8 @@ class Database:
                 t.store, batches,
                 None if priority is None else
                 [jnp.asarray(p, jnp.int32) for p in priority])
-            ok = np.concatenate([np.asarray(o) for o in oks])
+            with obs.span("namdb.sync.outcome"):
+                ok = np.concatenate([np.asarray(o) for o in oks])
             self._complete_bitvec(t, cids)
             self._assign_outcomes(
                 [s for g in writer_groups for s in g], ok, cids)
@@ -395,24 +416,25 @@ class Database:
     def _pack_txns(self, t: Table, sessions: List[Session]):
         """Batch one wave of writer sessions into a TxnBatch (T fixed W
         write slots, record -1 = unused) and claim its commit timestamps."""
-        writes = [s.writes() for s in sessions]
-        T = len(sessions)
-        W = max(r.shape[0] for r, _, _ in writes)
-        m = t.schema.payload_words
-        recs = np.full((T, W), -1, np.int32)
-        pay = np.zeros((T, W, m), np.uint32)
-        rcids = np.zeros((T, W), np.uint32)
-        for i, (r, p, rc) in enumerate(writes):
-            if r.shape[0]:
-                recs[i, :r.shape[0]] = r
-                pay[i, :r.shape[0]] = p
-                rcids[i, :r.shape[0]] = rc
-        cids = self.claim_cids(T)
-        txns = rsi.TxnBatch(write_recs=jnp.asarray(recs),
-                            read_cids=jnp.asarray(rcids),
-                            new_payload=jnp.asarray(pay),
-                            cid=jnp.asarray(cids))
-        return txns, cids
+        with obs.span("namdb.commit.pack"):
+            writes = [s.writes() for s in sessions]
+            T = len(sessions)
+            W = max(r.shape[0] for r, _, _ in writes)
+            m = t.schema.payload_words
+            recs = np.full((T, W), -1, np.int32)
+            pay = np.zeros((T, W, m), np.uint32)
+            rcids = np.zeros((T, W), np.uint32)
+            for i, (r, p, rc) in enumerate(writes):
+                if r.shape[0]:
+                    recs[i, :r.shape[0]] = r
+                    pay[i, :r.shape[0]] = p
+                    rcids[i, :r.shape[0]] = rc
+            cids = self.claim_cids(T)
+            txns = rsi.TxnBatch(write_recs=jnp.asarray(recs),
+                                read_cids=jnp.asarray(rcids),
+                                new_payload=jnp.asarray(pay),
+                                cid=jnp.asarray(cids))
+            return txns, cids
 
     def commit_pipelined(self, waves: List[List[Session]], *,
                          chunks: int = 1,
@@ -453,6 +475,8 @@ class Database:
                 table.store, txns_list)
             for (sessions, cids), ok in zip(writer_meta, oks):
                 self._complete_bitvec(table, cids)
+                with obs.span("namdb.sync.outcome"):
+                    ok = np.asarray(ok)
                 self._assign_outcomes(sessions, ok, cids)
         self._retry_losers([s for w in waves for s in w], chunks=chunks,
                            max_retries=max_retries)
@@ -562,42 +586,46 @@ class Database:
         the planner this shape's traced fabric byte counters plus the
         *cached-run* wall clock (compile time excluded) minus the variant's
         modeled compute share, so later plans are priced with the measured
-        wire rate.  Needs a fresh plan shape on this database — counters
-        accumulate at trace time only (see docs/fabric.md)."""
-        kind, alts, inputs = self._analyze(plan,
-                                           self._planner_for(profile, load))
-        variant = force_variant or Planner.chosen(alts)
-        if force_variant:
-            known = {a.name for a in alts}
-            if force_variant not in known:
-                raise ValueError(f"{force_variant!r} not in {sorted(known)}")
-        if kind == "join_agg":
-            join = plan.children[0]
-            rtab = self.table(join.children[0].scan_table())
-            stab = self.table(join.children[1].scan_table())
-            f = self._jit_join(variant, capacity_factor)
-            args = rtab.scan_arrays() + stab.scan_arrays()
-        else:
-            tab = self.table(plan.children[0].scan_table())
-            f = self._jit_agg(variant, plan.groups)
-            args = tab.scan_arrays()
-        before = self._stats_totals()
-        t0 = time.perf_counter()
-        value = jax.block_until_ready(f(*args))
-        elapsed = time.perf_counter() - t0
-        stats = self._stats_delta(before)
-        if calibrate:
-            t0 = time.perf_counter()
-            value = jax.block_until_ready(f(*args))   # now surely cached
-            elapsed = time.perf_counter() - t0
-            if stats:
-                self.planner.calibrate(
-                    stats, elapsed,
-                    compute_s=self.planner.compute_share(kind, variant,
-                                                         inputs))
+        wire rate, and returns that counter delta as ``stats`` (empty
+        without calibrate).  Needs a fresh plan shape on this database —
+        counters accumulate at trace time only (see docs/fabric.md)."""
+        with obs.span("namdb.execute", query=next(self._queries)):
+            with obs.span("namdb.execute.plan"):
+                kind, alts, inputs = self._analyze(
+                    plan, self._planner_for(profile, load))
+            variant = force_variant or Planner.chosen(alts)
+            if force_variant:
+                known = {a.name for a in alts}
+                if force_variant not in known:
+                    raise ValueError(
+                        f"{force_variant!r} not in {sorted(known)}")
+            if kind == "join_agg":
+                join = plan.children[0]
+                rtab = self.table(join.children[0].scan_table())
+                stab = self.table(join.children[1].scan_table())
+                f = self._jit_join(variant, capacity_factor)
+                args = rtab.scan_arrays() + stab.scan_arrays()
+            else:
+                tab = self.table(plan.children[0].scan_table())
+                f = self._jit_agg(variant, plan.groups)
+                args = tab.scan_arrays()
+            before = self._stats_totals() if calibrate else None
+            with obs.span("namdb.execute.run"):
+                value = jax.block_until_ready(f(*args))
+            stats = {}
+            if calibrate:
+                stats = self._stats_delta(before)
+                t0 = time.perf_counter()
+                with obs.span("namdb.execute.run"):
+                    value = jax.block_until_ready(f(*args))  # now cached
+                elapsed = time.perf_counter() - t0
+                if stats:
+                    self.planner.calibrate(
+                        stats, elapsed,
+                        compute_s=self.planner.compute_share(kind, variant,
+                                                             inputs))
         return QueryResult(value=value, variant=variant,
-                           alternatives=tuple(alts), plan=plan,
-                           elapsed_s=elapsed, stats=stats)
+                           alternatives=tuple(alts), plan=plan, stats=stats)
 
     def _jit_join(self, variant: str, capacity_factor: float):
         key = ("join", variant, capacity_factor)

@@ -25,6 +25,7 @@ from typing import Optional
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import rsi
 
 ISOLATION_LEVELS = ("rsi", "2pc")
@@ -39,6 +40,7 @@ class Session:
                              f"{ISOLATION_LEVELS}")
         self.db = db
         self.isolation = isolation
+        self.seq = next(db._sessions)       # the id of its oracle reads
         self.rid: Optional[int] = None      # read snapshot timestamp
         self.cid: Optional[int] = None      # assigned at commit (latest)
         self.committed: Optional[bool] = None
@@ -56,7 +58,8 @@ class Session:
     def begin(self, rid: Optional[int] = None) -> "Session":
         """Start a transaction; rid defaults to the oracle's current read
         timestamp (highest consecutively committed cid)."""
-        self.rid = self.db.read_timestamp() if rid is None else int(rid)
+        self.rid = (self.db.read_timestamp(session=self.seq) if rid is None
+                    else int(rid))
         self.cid = None
         self.committed = None
         self.txn_id = None
@@ -115,10 +118,12 @@ class Session:
             return self
         t = self.db.table(self._table)
         recs = np.concatenate(self._recs)
-        words = self.db.transport.read(t.store["words"],
-                                       jnp.asarray(recs, jnp.int32),
-                                       region=f"{t.schema.name}/words")
-        fresh = np.asarray(words, np.uint32) & np.uint32(int(rsi.CID_MASK))
+        with obs.span("namdb.sync.refresh"):
+            words = self.db.transport.read(t.store["words"],
+                                           jnp.asarray(recs, jnp.int32),
+                                           region=f"{t.schema.name}/words")
+            fresh = (np.asarray(words, np.uint32)
+                     & np.uint32(int(rsi.CID_MASK)))
         self._recs = [recs]
         self._payload = [np.concatenate(self._payload)]
         self._read_cids = [fresh]

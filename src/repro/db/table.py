@@ -22,6 +22,7 @@ from dataclasses import dataclass
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import rsi
 from repro.core.rsi import LOCK_BIT, WORD
 
@@ -105,7 +106,8 @@ class Table:
             self.store["payload"] = self.store["payload"].at[recs, 0].set(
                 jnp.asarray(vals, WORD))
         self.store = self._transport.place(self.store)
-        self.rows = max(self.rows, int(np.max(np.asarray(recs))) + 1)
+        with obs.span("namdb.sync.seed"):
+            self.rows = max(self.rows, int(np.max(np.asarray(recs))) + 1)
         return self
 
     # ------------------------------------------------------- partitioning --
@@ -154,7 +156,8 @@ class Table:
                 f"claim_locks on data table {self.schema.name!r}: the lock "
                 "column doubles as lock|CID words there; use a dedicated "
                 "(never seeded/loaded) lock table")
-        cand = np.nonzero(np.array(self.store["words"]) == 0)[0][:n]
+        with obs.span("namdb.sync.lock"):
+            cand = np.nonzero(np.array(self.store["words"]) == 0)[0][:n]
         if cand.size == 0:
             return []
         idx = jnp.asarray(cand, jnp.int32)
@@ -164,7 +167,8 @@ class Table:
                                         new,
                                         region=f"{self.schema.name}/words")
         self.store["words"] = words
-        return [int(i) for i in cand[np.array(ok)]]
+        with obs.span("namdb.sync.lock"):
+            return [int(i) for i in cand[np.array(ok)]]
 
     def release_lock(self, row: int, *, signaled: bool = False):
         """Unlock a claimed row (one-sided WRITE of the lock word).
@@ -187,5 +191,6 @@ class Table:
                 self.store["words"], idx, zero, region=region)
 
     def locked_rows(self) -> int:
-        return int(np.count_nonzero(np.array(self.store["words"]) &
-                                    np.uint32(1 << 31)))
+        with obs.span("namdb.sync.lock"):
+            return int(np.count_nonzero(np.array(self.store["words"]) &
+                                        np.uint32(1 << 31)))

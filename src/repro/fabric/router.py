@@ -384,16 +384,17 @@ def route(fields, dest=None, *, n: Optional[int] = None,
 def chunked_all_to_all(v, axis: str, n: int, cap: int, chunks: int = 1):
     """Paired all_to_all of a (n*cap, ...) buffer; chunks > 1 pipelines the
     transfer with a scan so chunk c's exchange overlaps chunk c+1's work."""
-    rest = v.shape[1:]
-    if chunks == 1:
-        return jax.lax.all_to_all(
-            v.reshape(n, cap, *rest), axis, 0, 0,
-            tiled=False).reshape(n * cap, *rest)
-    c = cap // chunks
-    vc = jnp.moveaxis(v.reshape(n, chunks, c, *rest), 1, 0)
+    with jax.named_scope("fabric.exchange"):
+        rest = v.shape[1:]
+        if chunks == 1:
+            return jax.lax.all_to_all(
+                v.reshape(n, cap, *rest), axis, 0, 0,
+                tiled=False).reshape(n * cap, *rest)
+        c = cap // chunks
+        vc = jnp.moveaxis(v.reshape(n, chunks, c, *rest), 1, 0)
 
-    def step(_, x):
-        return None, jax.lax.all_to_all(x, axis, 0, 0, tiled=False)
+        def step(_, x):
+            return None, jax.lax.all_to_all(x, axis, 0, 0, tiled=False)
 
-    _, out = jax.lax.scan(step, None, vc)
-    return jnp.moveaxis(out, 0, 1).reshape(n * cap, *rest)
+        _, out = jax.lax.scan(step, None, vc)
+        return jnp.moveaxis(out, 0, 1).reshape(n * cap, *rest)

@@ -311,9 +311,10 @@ class Transport:
         # itself, so hand it a plain single-chunk exchange of chunk width.
         exchange = (self._make_exchange(cap // chunks, 1) if overlap
                     else self._make_exchange(cap, chunks))
-        return _router.route(fields, dest, n=n, cap=cap, chunks=chunks,
-                             exchange=exchange, plan=plan, mask=mask,
-                             window=window, overlap=overlap)
+        with jax.named_scope("fabric.route"):
+            return _router.route(fields, dest, n=n, cap=cap, chunks=chunks,
+                                 exchange=exchange, plan=plan, mask=mask,
+                                 window=window, overlap=overlap)
 
     def route(self, fields, dest=None, *, cap: Optional[int] = None,
               chunks: int = 1, plan=None, mask=None,

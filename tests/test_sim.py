@@ -385,7 +385,7 @@ def test_database_stats_delta_survives_new_counters():
     db.load_table("R", keys, keys)
     db.load_table("S", keys, keys)
     q = db.scan("R").join(db.scan("S").filter(sel=0.5)).aggregate()
-    r = db.execute(q)
+    r = db.execute(q, calibrate=True)            # the delta's one reader
     assert r.stats                               # delta computed, no crash
     for verb, s in r.stats.items():
         assert isinstance(s.get("queue_hist", {}), dict)

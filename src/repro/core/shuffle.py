@@ -12,7 +12,9 @@ scatter-into-buffers step is the router's: packed single wire buffer,
 sort-free rank-in-bucket binning, and on TPU the Pallas
 ``repro.kernels.radix_partition`` software-managed-buffer kernel
 (jnp scatter elsewhere — see docs/fabric.md).  The *local* radix passes
-below keep their argsort form: they never touch the wire and the jaxpr
+and the build sort below sort each relation with its payload columns as
+operands of one stable ``lax.sort`` — no permutation gathers; the only
+gathers left are the probe's.  They never touch the wire, and the jaxpr
 sort-free guarantee is scoped to the route/cas/fetch_add hot paths.
 
 Relations are (keys, values) u32/u32; R is the (unique-key) build side.
@@ -27,32 +29,24 @@ from repro.core import bloom as bloom_mod
 MISS = jnp.uint32(0xFFFFFFFF)      # sentinel key: filtered / empty slot
 
 
-def radix_partition(keys, num_parts: int, *, bits_from: int = 0):
-    """Partition ids + stable order for a radix pass.
-    Returns (part_id (N,), order (N,), counts (P,))."""
-    part = ((keys >> bits_from) % jnp.uint32(num_parts)).astype(jnp.int32)
-    order = jnp.argsort(part, stable=True)
-    counts = jnp.zeros((num_parts,), jnp.int32).at[part].add(1)
-    return part, order, counts
+def radix_partition(keys, *cols, num_parts: int, bits_from: int = 0):
+    """A radix pass: ``keys`` and ``cols`` stably sorted by the partition
+    id ``(keys >> bits_from) % num_parts``, the columns carried as
+    operands of the sort.  Returns ``(keys, *cols)`` in partition order."""
+    part = (keys >> bits_from) % jnp.uint32(num_parts)
+    return jax.lax.sort((part, keys, *cols), num_keys=1, is_stable=True)[1:]
 
 
 def local_join(rk, rv, sk, sv):
     """Join unique-key build side R with probe side S.
     Returns (matched mask (|S|,), r-values aligned to S (|S|,))."""
     with jax.named_scope("join.sort"):
-        order = jnp.argsort(rk)
-        rks, rvs = rk[order], rv[order]
+        rks, rvs = jax.lax.sort((rk, rv), num_keys=1, is_stable=True)
     with jax.named_scope("join.probe"):
         pos = jnp.searchsorted(rks, sk)
         pos = jnp.clip(pos, 0, rks.shape[0] - 1)
         hit = rks[pos] == sk
         return hit, jnp.where(hit, rvs[pos], 0)
-
-
-def _cache_blocks(keys, vals, num_blocks):
-    """Radix pass 2: bin into cache-sized blocks (software-managed buffers)."""
-    part, order, _ = radix_partition(keys, num_blocks, bits_from=16)
-    return keys[order], vals[order]
 
 
 def join_agg(hit, rv, sv):
@@ -73,10 +67,11 @@ def ghj_local(rk, rv, sk, sv, *, num_parts: int = 32,
         # fixed-shape filter: drop misses by pointing them at a sentinel key
         sk = jnp.where(keep, sk, MISS)
     with jax.named_scope("join.radix"):
-        _, orderR, _ = radix_partition(rk, num_parts)
-        _, orderS, _ = radix_partition(sk, num_parts)
-        rk2, rv2 = _cache_blocks(rk[orderR], rv[orderR], num_parts)
-        sk2, sv2 = _cache_blocks(sk[orderS], sv[orderS], num_parts)
+        rk, rv = radix_partition(rk, rv, num_parts=num_parts)
+        sk, sv = radix_partition(sk, sv, num_parts=num_parts)
+        # pass 2: cache-sized blocks (software-managed buffers)
+        rk2, rv2 = radix_partition(rk, rv, num_parts=num_parts, bits_from=16)
+        sk2, sv2 = radix_partition(sk, sv, num_parts=num_parts, bits_from=16)
     hit, rvals = local_join(rk2, rv2, sk2, sv2)
     return join_agg(hit, rvals, sv2)
 
@@ -85,11 +80,10 @@ def rrj_local(rk, rv, sk, sv, *, num_blocks: int = 64):
     """RRJ collapses GHJ's network partition + radix pass into ONE radix pass
     straight into cache-sized remote buffers (paper §5.2)."""
     with jax.named_scope("join.radix"):
-        _, orderR, _ = radix_partition(rk, num_blocks)
-        _, orderS, _ = radix_partition(sk, num_blocks)
-        blocks = rk[orderR], rv[orderR], sk[orderS], sv[orderS]
-    hit, rvals = local_join(*blocks)
-    return join_agg(hit, rvals, sv[orderS])
+        rk, rv = radix_partition(rk, rv, num_parts=num_blocks)
+        sk, sv = radix_partition(sk, sv, num_parts=num_blocks)
+    hit, rvals = local_join(rk, rv, sk, sv)
+    return join_agg(hit, rvals, sv)
 
 
 # --------------------------------------------------------- distributed ----

@@ -1,5 +1,7 @@
-"""OLAP operators: joins (all four variants agree with ground truth) and
-aggregation (both schemes agree); cost-model sanity (Fig 7 crossovers)."""
+"""OLAP operators: joins (all four variants agree with ground truth; the
+local passes match a numpy argsort + take pipeline bit for bit and trace
+to no permutation gather) and aggregation (both schemes agree); cost-model
+sanity (Fig 7 crossovers)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -7,6 +9,7 @@ import pytest
 
 from repro.core import aggregation, bloom, costmodel, shuffle
 from repro.fabric import LocalTransport, MeshTransport
+from repro.fabric.check import iter_eqns
 from repro.launch.mesh import make_mesh
 
 
@@ -28,6 +31,116 @@ def test_local_join_variants_agree(rel):
     assert int(shuffle.ghj_local(rk, rv, sk, sv)) == expect
     assert int(shuffle.ghj_local(rk, rv, sk, sv, use_bloom=True)) == expect
     assert int(shuffle.rrj_local(rk, rv, sk, sv)) == expect
+
+
+MISS = 0xFFFFFFFF
+
+
+def _join_inputs(case):
+    """(rk, rv, sk, sv) u32 numpy columns; R's keys are unique but for
+    duplicate MISS slots, which carry distinct nonzero values."""
+    rng = np.random.default_rng(7)
+    rk = rng.permutation(np.arange(1, 513, dtype=np.uint32))
+    sk = rng.integers(1, 1024, 1024, dtype=np.uint32)
+    if case == "miss_and_empty_blocks":
+        # keys on every 4th residue mod 64, a quarter of R's above 2^18:
+        # most blocks of each radix pass stay empty
+        rk = (rk * 4 + (rk % 4 == 0) * (1 << 18)).astype(np.uint32)
+        sk = (sk * 4).astype(np.uint32)
+        rk[::7] = MISS
+        sk[::5] = MISS
+    elif case == "all_probes_miss":
+        rk[::3] = MISS
+        sk[:] = MISS
+    rv = rng.integers(1, 1 << 32, rk.shape[0], dtype=np.uint32)
+    sv = rng.integers(1, 1 << 32, sk.shape[0], dtype=np.uint32)
+    return rk, rv, sk, sv
+
+
+def _np_radix(keys, vals, num_parts, bits_from=0):
+    order = np.argsort((keys >> bits_from) % num_parts, kind="stable")
+    return np.take(keys, order), np.take(vals, order)
+
+
+@pytest.mark.parametrize("case", ["random", "miss_and_empty_blocks",
+                                  "all_probes_miss"])
+@pytest.mark.parametrize("variant", ["rrj", "ghj", "ghj_bloom"])
+def test_local_join_passes_match_numpy_argsort_take(variant, case,
+                                                    monkeypatch):
+    """Every payload sort of the local join (each radix pass of R and S,
+    then the build sort) yields the columns a stable numpy argsort + take
+    yields, bit for bit, and the aggregate is the numpy pipeline's sum."""
+    rk, rv, sk, sv = _join_inputs(case)
+    if variant == "ghj_bloom":     # the filter ghj_local applies to S
+        bits = bloom.build(jnp.asarray(rk), 1 << 20)
+        keep = np.array(bloom.query(bits, jnp.asarray(sk)))
+        sk_in = np.where(keep, sk, np.uint32(MISS))
+    else:
+        sk_in = sk
+    if variant == "rrj":
+        passes = [(64, 0)]
+    else:
+        passes = [(32, 0), (32, 16)]
+    want, r, s = [], (rk, rv), (sk_in, sv)
+    for num_parts, bits_from in passes:
+        r = _np_radix(*r, num_parts, bits_from)
+        s = _np_radix(*s, num_parts, bits_from)
+        want += [r, s]
+    order = np.argsort(r[0], kind="stable")
+    rks, rvs = np.take(r[0], order), np.take(r[1], order)
+    want.append((rks, rvs))
+    pos = np.clip(np.searchsorted(rks, s[0]), 0, rks.shape[0] - 1)
+    hit = rks[pos] == s[0]
+    prod = rvs[pos].astype(np.uint64) * s[1] & 0xFFFFFFFF
+    want_agg = int(np.where(hit, prod, 0).sum()) & 0xFFFFFFFF
+
+    got, real_sort = [], jax.lax.sort
+
+    def spy(operands, *args, **kw):
+        out = real_sort(operands, *args, **kw)
+        got.append(tuple(np.array(o) for o in out[-2:]))
+        return out
+    monkeypatch.setattr(jax.lax, "sort", spy)
+    args = [jnp.asarray(c) for c in (rk, rv, sk, sv)]
+    if variant == "rrj":
+        agg = shuffle.rrj_local(*args)
+    else:
+        agg = shuffle.ghj_local(*args, use_bloom=variant == "ghj_bloom")
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        for gc, wc in zip(g, w):
+            assert gc.dtype == np.uint32
+            np.testing.assert_array_equal(gc, wc)
+    assert int(agg) == want_agg
+
+
+@pytest.mark.parametrize("variant,sorts", [("rrj", 3), ("ghj", 5),
+                                           ("ghj_bloom", 5)])
+def test_local_join_gathers_only_in_the_probe(variant, sorts):
+    """The local passes carry their payloads through the sorts: outside
+    the searchsorted loop the only gathers are the probe's two ``[pos]``
+    gathers (plus the Bloom filter's bit lookups), one loop, and as many
+    sorts as relations sorted, each keyed on one operand, stable."""
+    col = jnp.zeros((256,), jnp.uint32)
+    if variant == "rrj":
+        fn = shuffle.rrj_local
+    else:
+        def fn(*a):
+            return shuffle.ghj_local(*a, use_bloom=variant == "ghj_bloom")
+    jaxpr = jax.make_jaxpr(fn)(col, col, col, col)
+    loops = {"scan", "while"}
+    eqns = list(iter_eqns(jaxpr))
+    assert sum(e.primitive.name in loops for _, e in eqns) == 1
+    gathers = [e for p, e in eqns
+               if e.primitive.name == "gather" and not loops & set(p)
+               and e.invars[0].aval.dtype != jnp.bool_]   # Bloom bits
+    assert len(gathers) == 2
+    assert all(str(e.source_info.name_stack) == "join.probe"
+               for e in gathers)
+    sort_eqns = [e for _, e in eqns if e.primitive.name == "sort"]
+    assert len(sort_eqns) == sorts
+    assert all(e.params["num_keys"] == 1 and e.params["is_stable"]
+               for e in sort_eqns)
 
 
 def test_distributed_join_one_shard(rel):

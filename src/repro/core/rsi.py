@@ -83,10 +83,27 @@ jax.tree_util.register_dataclass(
     meta_fields=[])
 
 
-def commit(store, txns: TxnBatch, *, transport=None, priority=None,
-           chunks: int = 1, exchange_chunks: int = 1, region_ns: str = ""):
+def _cat(parts):
+    """One table's (T, W, ...) array as it is; several tables' side by
+    side along the write slots (no op for one table, so that a one-table
+    wave traces to the program it always had)."""
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, 1)
+
+
+def commit(store, txns, *, transport=None, priority=None,
+           chunks: int = 1, exchange_chunks: int = 1, region_ns=""):
     """Commit a batch of concurrent transactions over a fabric transport.
     Returns (committed (T,) bool, new_store).
+
+    Several tables: ``store``, ``txns`` and ``region_ns`` may be tuples
+    with one entry per table the wave writes (every batch has the same T
+    rows, transaction t is row t of each, with the same ``cid``; a table
+    a transaction does not write holds -1 slots).  The prepare is ONE
+    routed round for the writes of every table, each request carrying its
+    table and routed to that table's home shard; a transaction commits
+    iff it won every lock in every table; each table then installs and
+    releases on its own routed round (payload widths differ by table).
+    Returns (committed, tuple of new stores).
 
     transport: fabric transport (default ``LocalTransport()``). Under
       ``MeshTransport`` store leaves are sharded on axis 0 by home shard
@@ -107,36 +124,59 @@ def commit(store, txns: TxnBatch, *, transport=None, priority=None,
       recorded so the race detector's lock-protocol rule can tie install
       WRITEs to this wave's CAS acquisitions.
     """
+    multi = isinstance(store, (tuple, list))
+    stores = tuple(store) if multi else (store,)
+    batches = tuple(txns) if multi else (txns,)
+    spaces = tuple(region_ns) if multi else (region_ns,)
+    K = len(stores)
     if transport is None:
         transport = LocalTransport()
-    T, _ = txns.write_recs.shape
+    T = batches[0].write_recs.shape[0]
+    if any(b.write_recs.shape[0] != T for b in batches):
+        raise ValueError("every table's batch holds the wave's T rows")
     if priority is None:
         priority = jnp.arange(T, dtype=jnp.int32)
     n = transport.n
     recorder = getattr(transport, "recorder", None)
     if recorder is not None:
-        recorder.begin_wave(f"{region_ns}commit")
+        recorder.begin_wave("".join(spaces) + "commit")
 
-    def body(words, payload, cids, bitvec, wrecs, rcids, npay, cid, prio):
-        Tl, W = wrecs.shape
+    def body(*args):
+        tabs = [list(args[4 * k:4 * k + 4]) for k in range(K)]
+        reqs = [args[4 * K + 3 * k:4 * K + 3 * k + 3] for k in range(K)]
+        cid, prio = args[-2:]
+        Tl = cid.shape[0]
+        Ws = [wr.shape[1] for wr, _, _ in reqs]
+        W = sum(Ws)
         me = transport.shard_index()
-        r_local = words.shape[0]       # records per home shard (contiguous)
-        bv_local = bitvec.shape[0]
         with jax.named_scope("rsi.prepare"):
-            # ---- route prepares to home shards (radix by rec // r_local);
-            # unused write slots are filtered (dest = n), not dropped.
-            dest = jnp.where(wrecs >= 0, wrecs // r_local, n)
+            # ---- route prepares to home shards (radix by rec // r_local
+            # of the request's table); unused write slots are filtered
+            # (dest = n), not dropped.
+            dest = _cat([jnp.where(wr >= 0, wr // t[0].shape[0], n)
+                         for (wr, _, _), t in zip(reqs, tabs)])
             flat_dest = dest.reshape(-1)
             cap = Tl * W  # worst case: all my writes hit one shard
             gid = jnp.repeat(prio, W)      # globally unique txn priority
+            wrecs = _cat([wr for wr, _, _ in reqs])
             recs_flat = wrecs.reshape(-1)
-            exp_flat = (rcids & CID_MASK).reshape(-1)
+            exp_flat = _cat([rc & CID_MASK for _, rc, _ in reqs]).reshape(-1)
             cid_flat = jnp.repeat(cid & CID_MASK, W)
-            npay_flat = npay.reshape(Tl * W, -1)
+            # each table's payloads in its own columns of the wave's slots
+            npay_flat, off = [], 0
+            for (_, _, npay), w in zip(reqs, Ws):
+                if K > 1:
+                    npay = jnp.pad(npay, ((0, 0), (off, W - off - w), (0, 0)))
+                npay_flat.append(npay.reshape(Tl * W, -1))
+                off += w
             # the CAS prepare is payload-free (paper msg 2): new CIDs and
             # payloads stay client-side until the install round trip
             req = {"rec": recs_flat, "exp": exp_flat, "prio": gid,
                    "slot": jnp.arange(Tl * W, dtype=jnp.int32)}
+            if K > 1:
+                tab_flat = _cat([jnp.full((Tl, w), k, jnp.int32)
+                                 for k, w in enumerate(Ws)]).reshape(-1)
+                req["tab"] = tab_flat
             # both rounds travel to the same home shards, so the slot
             # assignment is binned ONCE and reused for the install (the act
             # filter is a mask over the same plan — slots stay put, which is
@@ -145,11 +185,17 @@ def commit(store, txns: TxnBatch, *, transport=None, priority=None,
             res = transport.route(req, plan=plan, chunks=chunks)
             r, rvalid = res.fields, res.valid
         with jax.named_scope("rsi.grant"):
-            # ---- local CAS arbitration on my records (global prio = fair)
-            lrec = jnp.where(rvalid > 0, r["rec"] % r_local, -1)  # local row
-            ok, words = transport.cas(words, lrec, r["exp"],
-                                      LOCK_BIT | r["exp"], priority=r["prio"],
-                                      region=region_ns + "words")
+            # ---- local CAS arbitration on my records of each table
+            # (global prio = fair)
+            ok = None
+            for k, t in enumerate(tabs):
+                mine = rvalid > 0 if K == 1 else (rvalid > 0) & (r["tab"] == k)
+                lrec = jnp.where(mine, r["rec"] % t[0].shape[0], -1)
+                ok_k, t[0] = transport.cas(t[0], lrec, r["exp"],
+                                           LOCK_BIT | r["exp"],
+                                           priority=r["prio"],
+                                           region=spaces[k] + "words")
+                ok = ok_k if ok is None else ok | ok_k
             # ---- grants return to requesters (paired reverse exchange lands
             # each response in the slot it was sent from); the grant bit
             # crosses the collective in the packed u32 wire width
@@ -161,70 +207,87 @@ def commit(store, txns: TxnBatch, *, transport=None, priority=None,
             used = wrecs >= 0
             txn_ok = jnp.all(gmat | ~used, axis=1) & jnp.any(used, axis=1)
         with jax.named_scope("rsi.install"):
-            # ---- phase 2: installs routed the same way (write + unlock);
-            # committed txns install their CID, losers restore the old word.
+            # ---- phase 2: installs routed the same way (write + unlock),
+            # one round per table; committed txns install their CID,
+            # losers restore the old word.
             commit_req = jnp.repeat(txn_ok, W) & (granted > 0)
             release_req = (granted > 0) & ~commit_req
-            inst = {"rec": recs_flat,
-                    "val": jnp.where(commit_req, cid_flat, exp_flat),
-                    "npay": npay_flat,
-                    "do_pay": commit_req.astype(jnp.int32)}
+            val = jnp.where(commit_req, cid_flat, exp_flat)
+            do_pay = commit_req.astype(jnp.int32)
             act = commit_req | release_req
-            res2 = transport.route(inst, plan=plan, mask=act, chunks=chunks)
-            r2, v2 = res2.fields, res2.valid
-            lrec2 = jnp.where(v2 > 0, r2["rec"] % r_local, -1)
-            words = transport.write(words, lrec2, r2["val"],
-                                    region=region_ns + "words")
-            # version install: shift slots left, newest at 0.
-            # NB: negative indices WRAP in jnp scatters — use an explicit OOB
-            # sentinel (row N) so mode="drop" actually drops skipped writes.
-            oob = payload.shape[0]
-            pay_idx = jnp.where((r2["do_pay"] > 0) & (v2 > 0), lrec2, -1)
-            idx_pay = jnp.where(pay_idx >= 0, pay_idx, oob)
-            if payload.shape[1] > 1:
-                shifted_pay = jnp.concatenate(
-                    [payload[:, :1], payload[:, :-1]], axis=1)
-                shifted_cid = jnp.concatenate(
-                    [cids[:, :1], cids[:, :-1]], axis=1)
-                has_commit = jnp.zeros((oob,), bool).at[idx_pay].set(
-                    True, mode="drop")
-                payload = jnp.where(has_commit[:, None, None], shifted_pay,
-                                    payload)
-                cids = jnp.where(has_commit[:, None], shifted_cid, cids)
-            payload = payload.at[idx_pay, 0].set(r2["npay"], mode="drop")
-            cids = cids.at[idx_pay, 0].set(r2["val"], mode="drop")
-            # install bytes are already billed to the routed buffer; the
-            # scatter itself is invisible to the verbs, so log it record-only
-            # for the race detector's lock-protocol / conflict rules
-            transport.record_access("WRITE", region_ns + "payload", pay_idx,
-                                    region_len=oob)
-            transport.record_access("WRITE", region_ns + "cids", pay_idx,
-                                    region_len=oob)
+            for k, t in enumerate(tabs):
+                words, payload, cids, _ = t
+                inst = {"rec": recs_flat, "val": val, "npay": npay_flat[k],
+                        "do_pay": do_pay}
+                res2 = transport.route(
+                    inst, plan=plan, chunks=chunks,
+                    mask=act if K == 1 else act & (tab_flat == k))
+                r2, v2 = res2.fields, res2.valid
+                lrec2 = jnp.where(v2 > 0, r2["rec"] % words.shape[0], -1)
+                words = transport.write(words, lrec2, r2["val"],
+                                        region=spaces[k] + "words")
+                # version install: shift slots left, newest at 0.
+                # NB: negative indices WRAP in jnp scatters — use an
+                # explicit OOB sentinel (row N) so mode="drop" actually
+                # drops skipped writes.
+                oob = payload.shape[0]
+                pay_idx = jnp.where((r2["do_pay"] > 0) & (v2 > 0), lrec2, -1)
+                idx_pay = jnp.where(pay_idx >= 0, pay_idx, oob)
+                if payload.shape[1] > 1:
+                    shifted_pay = jnp.concatenate(
+                        [payload[:, :1], payload[:, :-1]], axis=1)
+                    shifted_cid = jnp.concatenate(
+                        [cids[:, :1], cids[:, :-1]], axis=1)
+                    has_commit = jnp.zeros((oob,), bool).at[idx_pay].set(
+                        True, mode="drop")
+                    payload = jnp.where(has_commit[:, None, None],
+                                        shifted_pay, payload)
+                    cids = jnp.where(has_commit[:, None], shifted_cid, cids)
+                payload = payload.at[idx_pay, 0].set(r2["npay"], mode="drop")
+                cids = cids.at[idx_pay, 0].set(r2["val"], mode="drop")
+                # install bytes are already billed to the routed buffer;
+                # the scatter itself is invisible to the verbs, so log it
+                # record-only for the race detector's lock-protocol /
+                # conflict rules
+                transport.record_access("WRITE", spaces[k] + "payload",
+                                        pay_idx, region_len=oob)
+                transport.record_access("WRITE", spaces[k] + "cids",
+                                        pay_idx, region_len=oob)
+                t[:3] = words, payload, cids
         with jax.named_scope("rsi.bitvec"):
-            # ---- timestamp bitvector [msg 3, unsignaled]: clients flip their
-            # own (locally owned) bits; aborted txns also burn their slot (the
-            # paper's wrap/skip bookkeeping). cids are pre-assigned in shard-
+            # ---- timestamp bitvector [msg 3, unsignaled]: clients flip
+            # their own (locally owned) bits in every table the wave
+            # writes; aborted txns also burn their slot (the paper's
+            # wrap/skip bookkeeping). cids are pre-assigned in shard-
             # contiguous ranges [me*bv_local, ...).
-            cbit = cid.astype(jnp.int32) - me * bv_local
-            cbit = jnp.where((cbit >= 0) & (cbit < bv_local), cbit, bv_local)
-            bitvec = bitvec.at[cbit].set(True, mode="drop")
-            transport.record_access(
-                "WRITE", region_ns + "bitvec",
-                jnp.where(cbit < bv_local, cbit, -1), region_len=bv_local)
-        return txn_ok, words, payload, cids, bitvec
+            for k, t in enumerate(tabs):
+                bitvec = t[3]
+                bv_local = bitvec.shape[0]
+                cbit = cid.astype(jnp.int32) - me * bv_local
+                cbit = jnp.where((cbit >= 0) & (cbit < bv_local), cbit,
+                                 bv_local)
+                t[3] = bitvec.at[cbit].set(True, mode="drop")
+                transport.record_access(
+                    "WRITE", spaces[k] + "bitvec",
+                    jnp.where(cbit < bv_local, cbit, -1),
+                    region_len=bv_local)
+        return (txn_ok,) + tuple(a for t in tabs for a in t)
 
-    txn_ok, words, payload, cids, bitvec = transport.run(
+    out = transport.run(
         body,
-        (store["words"], store["payload"], store["cids"], store["bitvec"],
-         txns.write_recs, txns.read_cids, txns.new_payload, txns.cid,
-         priority),
-        out_reps=(False, False, False, False, False))
+        tuple(s[f] for s in stores
+              for f in ("words", "payload", "cids", "bitvec"))
+        + tuple(a for b in batches
+                for a in (b.write_recs, b.read_cids, b.new_payload))
+        + (batches[0].cid, priority),
+        out_reps=(False,) * (1 + 4 * K))
     if recorder is not None:
         # the caller blocks on txn_ok, which rides the install round trip:
         # everything this wave installed happens-before whatever follows
         recorder.fence("commit-complete")
-    return txn_ok, {"words": words, "payload": payload, "cids": cids,
-                    "bitvec": bitvec}
+    new = tuple(dict(zip(("words", "payload", "cids", "bitvec"),
+                         out[1 + 4 * k:5 + 4 * k])) for k in range(K))
+    return out[0], (new if multi else new[0])
 
 
 def commit_pipelined(store, waves, *, transport=None, priority=None,

@@ -211,6 +211,11 @@ class Database:
         rounds reuse a single RoutePlan — the wave is binned to home
         shards once).  Returns the per-session committed mask.
 
+        Sessions may write several tables: the wave's writes in all of
+        them go through one routed prepare round (one install round per
+        table), every transaction takes one CID for all its tables, and
+        it commits in all of them or in none.
+
         max_retries: re-run aborted writers up to this many extra rounds.
         Each retry waits out :func:`backoff_slots` (deterministic jitter by
         txn id — replayable, no runtime RNG; priced as sim compute when a
@@ -237,21 +242,36 @@ class Database:
         # read-only sessions commit trivially under SI (no validate+lock)
         wave = sessions
         for s in wave:
-            if s.table_name is None:
+            if not s.tables:
                 s.committed = True
-        sessions = [s for s in wave if s.table_name is not None]
+        sessions = [s for s in wave if s.tables]
         if not sessions:
             return np.ones((len(wave),), bool)
-        names = {s.table_name for s in sessions}
-        if len(names) != 1:
-            raise ValueError(f"one table per commit wave, got {names}")
-        t = self.table(names.pop())
-        txns, cids = self._pack_txns(t, sessions)
-        ok, t.store = self._jit_commit(isolation, chunks,
-                                       f"{t.schema.name}/")(
-            t.store, txns,
-            None if priority is None else jnp.asarray(priority, jnp.int32))
-        self._complete_bitvec(t, cids)
+        # every table the wave writes, in one routed commit: one prepare
+        # round for all of them, one CID per transaction shared by all
+        tables = [self.table(n)
+                  for n in sorted({n for s in sessions for n in s.tables})]
+        if len(tables) > 1 and isolation != "rsi":
+            raise ValueError(
+                f"the {isolation} backend commits one table per wave, got "
+                f"{[t.schema.name for t in tables]}: multi-table "
+                "transactions commit under rsi")
+        txns, cids = self._pack_txns(tables, sessions)
+        prio = None if priority is None else jnp.asarray(priority, jnp.int32)
+        if len(tables) == 1:
+            t, = tables
+            ok, t.store = self._jit_commit(isolation, chunks,
+                                           f"{t.schema.name}/")(
+                t.store, txns[0], prio)
+        else:
+            ok, stores = self._jit_commit(
+                isolation, chunks,
+                tuple(f"{t.schema.name}/" for t in tables))(
+                tuple(t.store for t in tables), tuple(txns), prio)
+            for t, store in zip(tables, stores):
+                t.store = store
+        for t in tables:
+            self._complete_bitvec(t, cids)
         with obs.span("namdb.sync.outcome"):
             ok = np.asarray(ok)
         self._assign_outcomes(sessions, ok, cids)
@@ -266,11 +286,12 @@ class Database:
         slots (committed and aborted txns both burn theirs).  The store
         then goes back to its home-shard layout."""
         if self.transport.n > 1:
-            t.store["bitvec"] = self.transport.write(
-                t.store["bitvec"], jnp.asarray(cids, jnp.int32),
-                jnp.ones((len(cids),), bool),
-                region=f"{t.schema.name}/bitvec")
-            t.store = self.transport.place(t.store)
+            with obs.span("namdb.commit.complete"):
+                t.store["bitvec"] = self.transport.write(
+                    t.store["bitvec"], jnp.asarray(cids, jnp.int32),
+                    jnp.ones((len(cids),), bool),
+                    region=f"{t.schema.name}/bitvec")
+                t.store = self.transport.place(t.store)
 
     def _assign_outcomes(self, sessions, ok, cids):
         for s, committed, cid in zip(sessions, np.asarray(ok), cids):
@@ -289,8 +310,7 @@ class Database:
                       max_retries: int):
         """Bounded retry loop over a wave's aborted writers + outcome
         accounting for the whole wave (commits include read-only txns)."""
-        losers = [s for s in sessions
-                  if s.table_name is not None and not s.committed]
+        losers = [s for s in sessions if s.tables and not s.committed]
         self.txn_stats["aborts"] += len(losers)
         attempt = 1
         while losers and attempt <= max_retries:
@@ -313,32 +333,32 @@ class Database:
         self.txn_stats["commits"] += sum(bool(s.committed) for s in sessions)
 
     def _refresh_losers(self, losers: List[Session]):
-        """Batched retry refresh: ONE counted READ re-fetches the current
-        lock|CID word of every loser's write set (the retry wave pays one
-        verb, not one per session — same coalescing argument as group
-        commit), then each session revalidates against its slice.  Issued
-        after the losing round's commit-complete fence, which is what
-        makes the retry race-free (``fabric.check`` has the seeded
-        counterexample).  Equivalent to per-session
-        :meth:`Session.refresh_read_cids`."""
-        t = self.table(losers[0].table_name)
-        per = [np.concatenate(s._recs) for s in losers]
-        with obs.span("namdb.sync.refresh"):
-            words = self.transport.read(
-                t.store["words"],
-                jnp.asarray(np.concatenate(per), jnp.int32),
-                region=f"{t.schema.name}/words")
-            fresh = (np.asarray(words, np.uint32)
-                     & np.uint32(int(rsi.CID_MASK)))
+        """Batched retry refresh: per written table, ONE counted READ
+        re-fetches the current lock|CID word of every loser's write set in
+        it (the retry wave pays one verb per table, not one per session —
+        same coalescing argument as group commit), then each session
+        revalidates against its slice.  Issued after the losing round's
+        commit-complete fence, which is what makes the retry race-free
+        (``fabric.check`` has the seeded counterexample).  Equivalent to
+        per-session :meth:`Session.refresh_read_cids`."""
+        refreshed = []         # (session, table, its records' words)
+        for name in sorted({n for s in losers for n in s.tables}):
+            mine = [s for s in losers if name in s.tables]
+            per = [s.writes(name)[0] for s in mine]
+            t = self.table(name)
+            with obs.span("namdb.sync.refresh"):
+                words = self.transport.read(
+                    t.store["words"],
+                    jnp.asarray(np.concatenate(per), jnp.int32),
+                    region=f"{name}/words")
+                words = (np.asarray(words, np.uint32)
+                         & np.uint32(int(rsi.CID_MASK)))
+            split = np.cumsum([r.shape[0] for r in per])[:-1]
+            refreshed += [(s, name, w)
+                          for s, w in zip(mine, np.split(words, split))]
         rid = self.read_timestamp()
-        off = 0
-        for s, recs in zip(losers, per):
-            k = recs.shape[0]
-            s._recs = [recs]
-            s._payload = [np.concatenate(s._payload)]
-            s._read_cids = [fresh[off:off + k]]
-            s.rid = rid
-            off += k
+        for s, name, words in refreshed:
+            s.revalidate(name, words, rid)
 
     def _backoff(self, losers: List[Session], attempt: int):
         slots = sum(backoff_slots(s.txn_id or 0, attempt) for s in losers)
@@ -370,19 +390,15 @@ class Database:
         if any(s.isolation != "rsi" for s in flat):
             raise ValueError("commit_grouped is RSI-only")
         for s in flat:
-            if s.table_name is None:
+            if not s.tables:
                 s.committed = True
-        writer_groups = [[s for s in g if s.table_name is not None]
-                         for g in groups]
+        writer_groups = [[s for s in g if s.tables] for g in groups]
         writer_groups = [g for g in writer_groups if g]
         if writer_groups:
-            names = {s.table_name for g in writer_groups for s in g}
-            if len(names) != 1:
-                raise ValueError(f"one table per grouped commit, "
-                                 f"got {names}")
-            t = self.table(names.pop())
-            packed = [self._pack_txns(t, g) for g in writer_groups]
-            batches = [txns for txns, _ in packed]
+            t = self._one_table("commit_grouped",
+                                [s for g in writer_groups for s in g])
+            packed = [self._pack_txns([t], g) for g in writer_groups]
+            batches = [txns for (txns,), _ in packed]
             cids = np.concatenate([c for _, c in packed])
             oks, t.store = self._jit_commit_grouped(
                 chunks, f"{t.schema.name}/", len(batches))(
@@ -413,27 +429,44 @@ class Database:
             self._jit_cache[key] = jax.jit(fn)
         return self._jit_cache[key]
 
-    def _pack_txns(self, t: Table, sessions: List[Session]):
-        """Batch one wave of writer sessions into a TxnBatch (T fixed W
-        write slots, record -1 = unused) and claim its commit timestamps."""
-        with obs.span("namdb.commit.pack"):
-            writes = [s.writes() for s in sessions]
+    def _one_table(self, entry: str, sessions: List[Session]) -> Table:
+        names = {n for s in sessions for n in s.tables}
+        if len(names) != 1:
+            raise ValueError(
+                f"{entry} commits one table per wave, got {sorted(names)}: "
+                "commit multi-table transactions with Database.commit")
+        return self.table(names.pop())
+
+    def _pack_txns(self, tables: List[Table], sessions: List[Session]):
+        """Batch one wave of writer sessions into a TxnBatch per table
+        (the wave's T rows of fixed W write slots, record -1 = unused —
+        also where a transaction does not write the table) and claim its
+        commit timestamps: one per transaction, shared by its tables."""
+        with obs.span("namdb.commit.pack", tables=len(tables)):
             T = len(sessions)
-            W = max(r.shape[0] for r, _, _ in writes)
-            m = t.schema.payload_words
-            recs = np.full((T, W), -1, np.int32)
-            pay = np.zeros((T, W, m), np.uint32)
-            rcids = np.zeros((T, W), np.uint32)
-            for i, (r, p, rc) in enumerate(writes):
-                if r.shape[0]:
-                    recs[i, :r.shape[0]] = r
-                    pay[i, :r.shape[0]] = p
-                    rcids[i, :r.shape[0]] = rc
+            packed = []
+            for t in tables:
+                name = t.schema.name
+                writes = [s.writes(name) if name in s.tables else None
+                          for s in sessions]
+                W = max(w[0].shape[0] for w in writes if w is not None)
+                m = t.schema.payload_words
+                recs = np.full((T, W), -1, np.int32)
+                pay = np.zeros((T, W, m), np.uint32)
+                rcids = np.zeros((T, W), np.uint32)
+                for i, w in enumerate(writes):
+                    if w is not None and w[0].shape[0]:
+                        r, p, rc = w
+                        recs[i, :r.shape[0]] = r
+                        pay[i, :r.shape[0]] = p
+                        rcids[i, :r.shape[0]] = rc
+                packed.append((recs, rcids, pay))
             cids = self.claim_cids(T)
-            txns = rsi.TxnBatch(write_recs=jnp.asarray(recs),
-                                read_cids=jnp.asarray(rcids),
-                                new_payload=jnp.asarray(pay),
-                                cid=jnp.asarray(cids))
+            cid = jnp.asarray(cids)
+            txns = [rsi.TxnBatch(write_recs=jnp.asarray(recs),
+                                 read_cids=jnp.asarray(rcids),
+                                 new_payload=jnp.asarray(pay), cid=cid)
+                    for recs, rcids, pay in packed]
             return txns, cids
 
     def commit_pipelined(self, waves: List[List[Session]], *,
@@ -453,20 +486,20 @@ class Database:
             if any(s.isolation != "rsi" for s in w):
                 raise ValueError("commit_pipelined is RSI-only")
             for s in w:
-                if s.table_name is None:
+                if not s.tables:
                     s.committed = True
-            writers = [s for s in w if s.table_name is not None]
+            writers = [s for s in w if s.tables]
             if not writers:
                 continue
-            names = {s.table_name for s in writers}
-            if len(names) != 1:
-                raise ValueError(f"one table per commit wave, got {names}")
-            t = self.table(names.pop())
+            t = self._one_table("commit_pipelined", writers)
             if table is None:
                 table = t
             elif t is not table:
-                raise ValueError("one table per pipelined commit")
-            txns, cids = self._pack_txns(t, writers)
+                raise ValueError(
+                    "commit_pipelined commits one table across its waves, "
+                    f"got {table.schema.name!r} and {t.schema.name!r}: "
+                    "commit multi-table transactions with Database.commit")
+            (txns,), cids = self._pack_txns([t], writers)
             txns_list.append(txns)
             writer_meta.append((writers, cids))
         if txns_list:

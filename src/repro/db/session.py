@@ -12,6 +12,10 @@ write set into per-home-shard buffers once and the install round reuses
 the slots (``rsi.commit`` builds the plan, ``transport.plan_builds``
 counts it) — so each wave pays the rank-in-bucket pass once, not twice.
 
+A transaction may write several tables (the §4.3 checkout updates
+products and inserts an order and its order lines): its writes buffer per
+table, and they commit or abort together under one commit timestamp.
+
 The isolation backend is selectable per session behind the same API:
 ``"rsi"`` (default) is the paper's RDMA snapshot-isolation protocol;
 ``"2pc"`` is the traditional coordinator baseline (``repro.core.twopc``) —
@@ -25,7 +29,6 @@ from typing import Optional
 import jax.numpy as jnp
 import numpy as np
 
-from repro import obs
 from repro.core import rsi
 
 ISOLATION_LEVELS = ("rsi", "2pc")
@@ -48,10 +51,9 @@ class Session:
                                             # across retries (backoff
                                             # jitter keys off it)
         self.attempts: int = 0              # fabric commit rounds run
-        self._table: Optional[str] = None   # single-table txn (v1)
-        self._recs: list = []
-        self._payload: list = []
-        self._read_cids: list = []
+        # buffered writes per table, in the order first written:
+        # name -> [(recs (W,), payload (W, m), read_cids (W,)), ...]
+        self._writes: dict = {}
 
     # ----------------------------------------------------------- txn API --
 
@@ -64,8 +66,7 @@ class Session:
         self.committed = None
         self.txn_id = None
         self.attempts = 0
-        self._table, self._recs = None, []
-        self._payload, self._read_cids = [], []
+        self._writes = {}
         return self
 
     def get(self, table, recs):
@@ -82,23 +83,17 @@ class Session:
 
     def put(self, table, recs, payload, read_cids=None):
         """Buffer writes: recs (W,), payload (W, m); read_cids (W,) is the
-        CID each record was read under (None = blind insert at CID 0)."""
+        CID each record was read under (None = blind insert at CID 0).
+        Writes to several tables buffer per table and commit together."""
         self._check_open()
         t = self.db.table(table)
-        name = t.schema.name
-        if self._table is not None and self._table != name:
-            raise NotImplementedError(
-                f"multi-table transaction ({self._table} + {name}): one "
-                "store per routed commit in v1")
-        self._table = name
         recs = np.asarray(recs, np.int32).reshape(-1)
         payload = np.asarray(payload, np.uint32).reshape(
             recs.shape[0], t.schema.payload_words)
         rcids = (np.zeros(recs.shape[0], np.uint32) if read_cids is None
                  else np.asarray(read_cids, np.uint32).reshape(-1))
-        self._recs.append(recs)
-        self._payload.append(payload)
-        self._read_cids.append(rcids)
+        self._writes.setdefault(t.schema.name, []).append(
+            (recs, payload, rcids))
         return self
 
     def commit(self, **kw) -> bool:
@@ -108,26 +103,14 @@ class Session:
 
     def refresh_read_cids(self) -> "Session":
         """Retry path after an abort: re-read the *current* committed
-        version of every buffered write record (ONE counted READ on the
-        table's word array — issued after the losing round's
+        version of every buffered write record (ONE counted READ on each
+        written table's word array — issued after the losing round's
         commit-complete fence, which is what makes the retry race-free)
         and revalidate the buffered writes against it.  The payload stays
         as buffered — fig_scale's increments are idempotent re-applies;
         an application would re-run its read-modify-write here."""
-        if self._table is None:
-            return self
-        t = self.db.table(self._table)
-        recs = np.concatenate(self._recs)
-        with obs.span("namdb.sync.refresh"):
-            words = self.db.transport.read(t.store["words"],
-                                           jnp.asarray(recs, jnp.int32),
-                                           region=f"{t.schema.name}/words")
-            fresh = (np.asarray(words, np.uint32)
-                     & np.uint32(int(rsi.CID_MASK)))
-        self._recs = [recs]
-        self._payload = [np.concatenate(self._payload)]
-        self._read_cids = [fresh]
-        self.rid = self.db.read_timestamp()
+        if self._writes:
+            self.db._refresh_losers([self])
         return self
 
     # ---------------------------------------------------------- internals --
@@ -137,19 +120,23 @@ class Session:
             raise RuntimeError("call begin() first")
 
     @property
-    def table_name(self) -> Optional[str]:
-        return self._table
+    def tables(self) -> tuple:
+        """Names of the tables this transaction writes, in the order first
+        written (empty for a read-only transaction)."""
+        return tuple(self._writes)
 
-    def writes(self):
+    def writes(self, table: str):
         """(recs (W,), payload (W, m), read_cids (W,)) — the buffered
-        write set, concatenated."""
-        if not self._recs:
-            return (np.zeros((0,), np.int32),
-                    np.zeros((0, 0), np.uint32),
-                    np.zeros((0,), np.uint32))
-        return (np.concatenate(self._recs),
-                np.concatenate(self._payload),
-                np.concatenate(self._read_cids))
+        write set in ``table``, concatenated."""
+        return tuple(np.concatenate(x) for x in zip(*self._writes[table]))
+
+    def revalidate(self, table: str, read_cids, rid: int):
+        """Validate the writes buffered for ``table`` against
+        ``read_cids`` (its records' words, re-read after an abort) from
+        snapshot ``rid`` on."""
+        recs, payload, _ = self.writes(table)
+        self._writes[table] = [(recs, payload, read_cids)]
+        self.rid = rid
 
     # -------------------------------------------------------- context mgr --
 
@@ -157,6 +144,6 @@ class Session:
         return self.begin()
 
     def __exit__(self, exc_type, exc, tb):
-        if exc_type is None and self.committed is None and self._recs:
+        if exc_type is None and self.committed is None and self._writes:
             self.commit()
         return False

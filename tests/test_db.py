@@ -168,8 +168,11 @@ def test_session_guards():
         s.get("a", [0])
     s.begin()
     s.put("a", [0], np.ones((1, 1), np.uint32))
-    with pytest.raises(NotImplementedError, match="multi-table"):
-        s.put("b", [0], np.ones((1, 1), np.uint32))
+    s.put("b", [1, 2], np.ones((2, 1), np.uint32))    # two tables buffer
+    assert s.tables == ("a", "b")
+    np.testing.assert_array_equal(s.writes("b")[0], [1, 2])
+    with pytest.raises(ValueError, match="one table per wave"):
+        db.commit_grouped([[s]])
     with pytest.raises(ValueError, match="isolation"):
         db.session(isolation="3pc")
 

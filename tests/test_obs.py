@@ -237,7 +237,7 @@ def test_the_commit_program_keeps_its_name_and_carries_rsi_scopes():
     db, t = _checkout_db()
     s = db.session().begin()
     s.put(t, np.arange(3), np.ones((3, 4), np.uint32))
-    txns, _ = db._pack_txns(t, [s])
+    (txns,), _ = db._pack_txns([t], [s])
     text = db._jit_commit("rsi", 1, "p/").lower(
         t.store, txns, None).as_text(debug_info=True)
     assert "module @jit__lambda" in text

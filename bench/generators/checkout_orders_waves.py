@@ -113,6 +113,7 @@ class Cell(checkout_waves.Cell):
                for n, sl in self.slots.items()}
         wave = checkout_waves._Wave()
         wave.t_begin = np.empty(self.sessions)
+        wave.begin_s = 0.0
         wave.failed = False
         try:
             with span("bench.wave_build"):
@@ -124,6 +125,7 @@ class Cell(checkout_waves.Cell):
                 for i in range(self.sessions):
                     wave.t_begin[i] = time.perf_counter()
                     s = db.session().begin()
+                    wave.begin_s += time.perf_counter() - wave.t_begin[i]
                     s.put(t["products"], recs["products"][i],
                           pay["products"][i], read_cids=wave.rids[i])
                     s.put(t["orders"], recs["orders"][i], pay["orders"][i])

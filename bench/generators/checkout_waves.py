@@ -8,7 +8,8 @@ wave starts when the commit returns.  Product keys (uniform) and write
 payloads are drawn from the seed at set-up for every wave the insert
 region can hold; a run that would need more fails (the region never
 wraps).  After the window every record that any wave wrote is read back
-through ``db.snapshot_read``, ``READBACK_ROWS`` records per call.
+through ``db.snapshot_read``, ``READBACK_ROWS`` records per call.  Each
+wave keeps the host time its sessions spent in ``begin`` (``begin_s``).
 
 Traffic parameters: ``sessions_per_wave``; ``warmup_waves`` (set-up
 waves, checked like the others).
@@ -46,7 +47,8 @@ READBACK_ROWS = 1 << 16
 
 
 class _Wave:
-    __slots__ = ("rids", "ok", "cids", "t_begin", "t_end", "failed")
+    __slots__ = ("rids", "ok", "cids", "t_begin", "begin_s", "t_end",
+                 "failed")
 
 
 class Cell:
@@ -104,6 +106,7 @@ class Cell:
         pay = ref.write_payload(self.write_seeds[w], self.words)
         wave = _Wave()
         wave.t_begin = np.empty(self.sessions)
+        wave.begin_s = 0.0
         wave.failed = False
         try:
             with span("bench.wave_build"):
@@ -114,6 +117,7 @@ class Cell:
                 for i in range(self.sessions):
                     wave.t_begin[i] = time.perf_counter()
                     s = db.session().begin()
+                    wave.begin_s += time.perf_counter() - wave.t_begin[i]
                     s.put(t, recs[i, :upd], pay[i, :upd],
                           read_cids=wave.rids[i])
                     s.put(t, recs[i, upd:], pay[i, upd:])

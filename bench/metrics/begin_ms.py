@@ -1,10 +1,12 @@
-"""Host time of a checkout wave's ``Session.begin`` calls taking their
-read snapshots, in ms: the program's ``namdb.sync.oracle`` spans tagged
-with a ``session`` (the oracle reads of ``Session.begin``) per
-``namdb.commit`` (one per wave) in the window."""
-from bench import program_spans as ps
+"""Host time of a checkout wave's ``Session.begin`` calls, in ms: the
+generator's clock around each ``db.session().begin()`` (the session's
+set-up and its read snapshot from the timestamp oracle), summed over the
+wave's sessions and averaged over the window's waves.  It reads wherever
+the window holds a wave, whether the begins sync with the device or
+not."""
 
 
 def read(ctx):
-    return ps.per_unit(ps.checkout(ctx), lambda n: n == "namdb.sync.oracle",
-                       ps.ms, keep=lambda r: "session" in r.ids)
+    waves = [w for w in ctx.cell.waves[ctx.cell.first:] if not w.failed]
+    return 1e3 * sum(w.begin_s for w in waves) / len(waves) \
+        if waves else None

@@ -1,5 +1,6 @@
 """Device time of sort ops per query, in ms, averaged over the chips: the
-local radix passes' argsorts and the sort-probe's argsort."""
+local radix passes and the build side's sort, each a stable sort that
+carries its relation's payload columns as operands."""
 
 
 def read(ctx):

@@ -55,16 +55,16 @@ def join(ctx):
                   "namdb.execute")
 
 
-def per_unit(win, kind, value, keep=lambda r: True):
+def per_unit(win, kind, value):
     """Sum of ``value(record)`` over the records whose name ``kind``
-    accepts and that ``keep`` keeps, per unit; None where the window holds
-    no unit or no record of the kind."""
+    accepts, per unit; None where the window holds no unit or no record
+    of the kind."""
     if win is None or win is ZERO:
         return None if win is None else 0.0
     of_kind = [r for r in win.records if kind(r.name)]
     if not win.units or not of_kind:
         return None
-    return sum(value(r) for r in of_kind if keep(r)) / win.units
+    return sum(value(r) for r in of_kind) / win.units
 
 
 def ms(record) -> float:

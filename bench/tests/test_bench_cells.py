@@ -37,8 +37,8 @@ def test_cell_runs_correct(root, workload, traced):
     assert all(c["value"] <= c["limit"] for c in out["checks"].values())
     spec = _spec(root)
     if traced:
-        # the CPU has no device trace: the host-span readers report, the
-        # device readers find nothing and stay silent
+        # the CPU has no device trace: the host-clock readers report,
+        # the device readers find nothing and stay silent
         names = {m["name"] for m in spec["per_layer"]
                  if workload in m["workloads"]}
         assert set(out["metrics"]) <= names
@@ -46,7 +46,7 @@ def test_cell_runs_correct(root, workload, traced):
         assert "breakdown" in out
         if workload == "checkout.uniform":
             assert set(out["metrics"]) == {"wave_build_ms",
-                                           "commit_call_ms"}
+                                           "commit_call_ms", "begin_ms"}
     else:
         names = {m["name"] for m in spec["end_to_end"]
                  if workload in m.get("workloads", [workload])}

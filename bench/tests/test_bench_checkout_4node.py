@@ -74,8 +74,9 @@ def test_the_cell_runs_correct_on_four_devices(runs, mode):
         assert set(out["metrics"]) == {"txn_per_s", "txn_p95_ms",
                                        "setup_s"}
     else:
-        # the CPU trace has no chip: only the benchmark-span readers
-        assert set(out["metrics"]) == {"wave_build_ms", "commit_call_ms"}
+        # the CPU trace has no chip: only the host-clock readers
+        assert set(out["metrics"]) == {"wave_build_ms", "commit_call_ms",
+                                       "begin_ms"}
 
 
 def test_the_control_is_torn(runs):
@@ -93,8 +94,9 @@ def test_the_cell_is_listed_with_its_metrics():
     assert cell.chips == 4
     assert {m["name"] for m in cell.end_to_end} == {
         "txn_per_s", "txn_p95_ms", "setup_s"}
-    assert {"all_to_all_ms.checkout", "commit_complete_ms"} <= {
-        m["name"] for m in cell.per_layer}
+    assert {"all_to_all_ms.checkout", "commit_complete_ms", "begin_ms",
+            "device_syncs_per_wave", "commit_pack_ms",
+            "window_compiles.checkout"} <= {m["name"] for m in cell.per_layer}
 
 
 def _window_trace(ops, waves):

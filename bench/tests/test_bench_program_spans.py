@@ -1,7 +1,8 @@
 """The readers of the program's own spans and counters, on the tiny cells
 run through their generators on the CPU: with a trace that has a chip
 they read the program's span log, with the CPU's empty trace nothing, and
-on a chip a window without the spans they read stops the run."""
+on a chip a window without the spans they read stops the run.  And
+``begin_ms``, which the generators' clock feeds, on the same cells."""
 import dataclasses
 import json
 import os
@@ -15,9 +16,10 @@ from bench import harness, trace
 from bench.tests.tiny import tiny_root
 
 SEED = (1 << 31) + 977
-CHECKOUT = ("begin_ms", "device_syncs_per_wave", "commit_pack_ms",
+CHECKOUT = ("device_syncs_per_wave", "commit_pack_ms",
             "window_compiles.checkout")
 JOIN = ("window_compiles.join",)
+CHECKOUT_CELLS = ["checkout.uniform", "checkout.4node"]
 
 
 @pytest.fixture(scope="module")
@@ -57,7 +59,7 @@ def _chip():
 
 def test_the_metrics_are_listed_for_their_cells(root):
     spec = json.load(open(os.path.join(root, "BENCHMARK.json")))
-    for names, cells in ((CHECKOUT, ["checkout.uniform"]),
+    for names, cells in ((CHECKOUT + ("begin_ms",), CHECKOUT_CELLS),
                          (JOIN, ["join.1node", "join.4node"])):
         for name in names:
             m, = [m for m in spec["per_layer"] if m["name"] == name]
@@ -69,7 +71,7 @@ def test_checkout_readers_read_the_program_spans(root, checkout):
     got = {n: _read(root, n, sut, _chip()) for n in CHECKOUT}
     assert got["device_syncs_per_wave"] == cell.traffic[
         "sessions_per_wave"] + 3
-    assert got["begin_ms"] > 0 and got["commit_pack_ms"] > 0
+    assert got["commit_pack_ms"] > 0
     assert got["window_compiles.checkout"] == 0
 
 
@@ -134,8 +136,7 @@ def test_a_renamed_span_is_not_read_as_zero(root, checkout, monkeypatch):
                 for r in real(lo, hi)]
     monkeypatch.setattr(obs, "spans", renamed)
     _, sut = checkout
-    for name in ("begin_ms", "device_syncs_per_wave"):
-        assert _read(root, name, sut, _chip()) is None, name
+    assert _read(root, "device_syncs_per_wave", sut, _chip()) is None
     assert _read(root, "commit_pack_ms", sut, _chip()) > 0
 
 
@@ -152,3 +153,30 @@ def test_a_compile_in_the_window_is_counted(root):
     # the lambda's trace and compile, and the trace of the jitted add in it
     assert _read(root, "window_compiles.checkout", _window(body),
                  _chip()) >= 2
+
+
+def _wave(begin_s, failed=False):
+    return SimpleNamespace(begin_s=begin_s, failed=failed)
+
+
+def test_begin_ms_reads_the_generators_begin_time(root):
+    """The begins' host time per wave, over the window's waves that have
+    an outcome: a set-up wave and a failed one do not count.  It is a
+    host-clock metric, so it reads with or without a chip."""
+    cell = SimpleNamespace(first=1, waves=[
+        _wave(9.0), _wave(0.25), _wave(0.75), _wave(5.0, failed=True)])
+    for tr in (_chip(), trace.Trace()):
+        assert _read(root, "begin_ms", cell, tr) == pytest.approx(500.0)
+    assert _read(root, "begin_ms", SimpleNamespace(
+        first=1, waves=[_wave(9.0)]), _chip()) is None
+
+
+def test_begin_ms_reads_a_run_of_the_generator(root, checkout):
+    """On the tiny cell every window wave times its begins, and the
+    begins are part of the wave's build."""
+    _, sut = checkout
+    waves = sut.waves[sut.first:]
+    assert waves and all(0 < w.begin_s < w.t_end - w.t_begin[0]
+                         for w in waves)
+    assert _read(root, "begin_ms", sut, _chip()) == pytest.approx(
+        1e3 * sum(w.begin_s for w in waves) / len(waves))
